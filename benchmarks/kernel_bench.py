@@ -1,8 +1,8 @@
-"""Kernel structural benchmark (no TPU available: dry-run profiling style).
+"""Kernel structural benchmark: block counts, no timings.
 
-On this CPU container the Pallas kernels execute in interpret mode, so
-wall-clock numbers would be meaningless.  What IS measurable and transfers
-to hardware is the *structural* work saved by the bin-packing-aware designs:
+It runs no kernel; a kernel's time is measured only on a TPU.  What it
+counts from the kernels' grid logic is the *structural* work saved by the
+bin-packing-aware designs:
 
   - packed_attention: fraction of (q, kv) tile pairs skipped by the causal
     block-skip, and the FLOPs a dense (non-packed, padded) batch would have

@@ -92,7 +92,7 @@ def part2_real_model() -> None:
             jnp.arange(prompt_len, dtype=jnp.int32), (B, prompt_len)
         ),
     }
-    logits, cache = model.prefill(params, batch)
+    logits, cache = model.prefill(params, batch, max_len=prompt_len + gen)
     print(f"prefilled {B} sequences of {prompt_len} tokens")
 
     # paged bookkeeping for the decode slots (bins = HBM pages)

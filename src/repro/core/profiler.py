@@ -149,37 +149,30 @@ class WorkerProbe:
     """
 
     def __init__(self) -> None:
-        # Running (sum, count) per image — bit-identical to accumulating a
-        # list and taking sum()/len() at report time (same left-to-right
-        # float addition order), without growing per-tick Python lists.
-        self._sum: Dict[str, float] = {}
-        self._n: Dict[str, int] = {}
+        # Per-image sample lists, averaged with the builtin ``sum`` at report
+        # time.  From Python 3.12 ``sum`` of floats compensates rounding, so
+        # a running ``+=`` would drift from it in the last bit.
+        self._vals: Dict[str, list] = {}
 
     def sample(self, pe_usages: Iterable[Tuple[str, float]]) -> None:
         """Accumulate one round of (image, usage) samples."""
-        acc, counts = self._sum, self._n
+        acc = self._vals
         for image, usage in pe_usages:
             if image in acc:
-                acc[image] += float(usage)
-                counts[image] += 1
+                acc[image].append(float(usage))
             else:
-                acc[image] = float(usage)
-                counts[image] = 1
+                acc[image] = [float(usage)]
 
-    def accumulators(self) -> Tuple[Dict[str, float], Dict[str, int]]:
-        """The live (sum, count) dicts — the simulation's per-PE fast path.
-
-        Callers may accumulate into these directly (same semantics as one
-        ``sample()`` call per entry: add to the sum, bump the count); the
-        representation is owned here so ``report()`` and the hot loop can
-        never drift apart.
+    def samples(self) -> Dict[str, list]:
+        """The live per-image sample lists — the simulation's per-PE fast
+        path appends to them directly (same semantics as one ``sample()``
+        call per entry); the representation is owned here so ``report()``
+        and the hot loop can never drift apart.
         """
-        return self._sum, self._n
+        return self._vals
 
     def report(self) -> Dict[str, float]:
         """Flush: per-image mean since the last report (sent to the master)."""
-        counts = self._n
-        out = {image: s / counts[image] for image, s in self._sum.items()}
-        self._sum = {}
-        self._n = {}
+        out = {image: sum(v) / len(v) for image, v in self._vals.items()}
+        self._vals = {}
         return out

@@ -353,7 +353,7 @@ class SimCluster:
             return vout
         out = [0.0] * len(workers)
         for idx in self._active_idx:
-            load = 0.0
+            loads = []
             for pe in workers[idx].pes:
                 if pe.state is stopped:
                     continue
@@ -361,8 +361,10 @@ class SimCluster:
                 v = cache.get(img)
                 if v is None:
                     v = cache[img] = est(img)
-                load += v
-            out[idx] = load
+                loads.append(v)
+            # the builtin sum, as the frozen reference does: from Python
+            # 3.12 it compensates float rounding, so ``+=`` drifts from it
+            out[idx] = sum(loads)
         return out
 
     def backlog_resource_demand(self) -> Optional[Resources]:
@@ -621,7 +623,7 @@ class SimCluster:
         for idx in self._active_idx:
             w = self.workers[idx]
             totals = np.zeros(D)
-            acc, counts = w.probe.accumulators()
+            acc = w.probe.samples()
             for pe in w.pes:
                 vec = np.zeros(D)
                 if pe.state is busy and pe.msg is not None:
@@ -640,11 +642,9 @@ class SimCluster:
                 totals = totals + vec
                 img = pe.image
                 if img in acc:
-                    acc[img] = acc[img] + vec
-                    counts[img] += 1
+                    acc[img].append(vec)
                 else:
-                    acc[img] = vec
-                    counts[img] = 1
+                    acc[img] = [vec]
             clipped = np.minimum(totals, 1.0)
             dim_out[w.idx] = clipped
             out[w.idx] = clipped[0]
@@ -670,7 +670,7 @@ class SimCluster:
             cores = 0.0
             # accumulate straight into the probe's per-image running means
             # (same order and float addition as WorkerProbe.sample)
-            acc, counts = w.probe.accumulators()
+            acc = w.probe.samples()
             for pe in w.pes:
                 if pe.state is busy and pe.msg is not None:
                     draw = pe.msg.cpu_cores * float(rng_normal(1.0, noise_std))
@@ -686,11 +686,9 @@ class SimCluster:
                 cores += draw
                 img = pe.image
                 if img in acc:
-                    acc[img] += draw / cores_per_worker
-                    counts[img] += 1
+                    acc[img].append(draw / cores_per_worker)
                 else:
-                    acc[img] = draw / cores_per_worker
-                    counts[img] = 1
+                    acc[img] = [draw / cores_per_worker]
             u = cores / cores_per_worker
             out[w.idx] = u if u < 1.0 else 1.0
         return out

@@ -35,6 +35,7 @@ __all__ = [
     "param_shardings",
     "batch_shardings",
     "cache_shardings",
+    "bytes_by_device",
 ]
 
 Rules = Dict[str, Tuple[str, ...]]
@@ -250,3 +251,14 @@ def cache_shardings(cache: Any, mesh: Mesh, rules: Optional[Rules] = None) -> An
             NamedSharding(mesh, axes_to_pspec(axes, leaf.shape, rules, mesh))
         )
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def bytes_by_device(tree: Any) -> Dict[int, int]:
+    """Bytes of the arrays in ``tree`` that each device holds, by id."""
+    held: Dict[int, int] = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in leaf.addressable_shards:
+            held[shard.device.id] = (
+                held.get(shard.device.id, 0) + shard.data.nbytes
+            )
+    return held

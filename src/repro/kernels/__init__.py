@@ -1,3 +1,6 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels: grouped matmul, packed attention, paged attention.
+
+Each kernel has ``kernel.py`` (the Pallas program), ``ref.py`` (the plain
+jnp oracle) and ``ops.py`` (the jitted wrapper).  How a kernel runs is
+decided in one place, ``dispatch.pallas_interpret``.
+"""

@@ -1,6 +1,7 @@
-"""Jit'd public wrapper for the grouped matmul: dispatches kernel (TPU),
-interpret (CPU validation), or jnp reference, and provides the fused SwiGLU
-expert-FFN built from three grouped GEMMs."""
+"""Jit'd public wrapper for the grouped matmul: runs the kernel (compiled on
+a TPU, interpreted only when asked; see ``kernels.dispatch``) or the jnp
+reference, and provides the fused SwiGLU expert-FFN built from three grouped
+GEMMs."""
 
 from __future__ import annotations
 
@@ -9,14 +10,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..dispatch import pallas_interpret
 from .kernel import grouped_matmul
 from .ref import grouped_matmul_ref
 
 __all__ = ["gmm", "expert_ffn_swiglu"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
@@ -30,7 +28,7 @@ def gmm(
 ) -> jax.Array:
     if use_kernel:
         return grouped_matmul(
-            x, w, group_sizes, interpret=interpret or not _on_tpu()
+            x, w, group_sizes, interpret=pallas_interpret(interpret)
         )
     return grouped_matmul_ref(x, w, group_sizes)
 

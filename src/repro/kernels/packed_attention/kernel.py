@@ -9,6 +9,9 @@ flash-attention structure adapted to the TPU memory hierarchy:
     scratch and survives across the kv sweep;
   - Q/K/V tiles are (block_q x head_dim) / (block_kv x head_dim) VMEM blocks
     with head_dim the 128-lane minor dimension (MXU-aligned);
+  - segment ids enter as a (block_q, 1) column for the queries and a
+    (1, block_kv) row for the keys, so each block's last two dimensions
+    meet the TPU tiling rule and the mask is a plain broadcast compare;
   - logits/softmax accumulate in fp32 on the MXU (bf16 operands);
   - *block skipping*: a (q, kv) tile pair is skipped entirely when causality
     excludes it (kv block strictly above the diagonal).  Segment masking is
@@ -37,14 +40,14 @@ _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def _attn_kernel(
-    seg_q_ref,   # (1, block_q) int32
-    seg_kv_ref,  # (1, block_kv) int32
+    seg_q_ref,   # (1, block_q, 1) int32
+    seg_kv_ref,  # (1, 1, block_kv) int32
     q_ref,       # (1, 1, block_q, D)
     k_ref,       # (1, 1, block_kv, D)
     v_ref,       # (1, 1, block_kv, D)
     o_ref,       # (1, 1, block_q, D)
-    m_ref,       # VMEM (block_q,) f32
-    l_ref,       # VMEM (block_q,) f32
+    m_ref,       # VMEM (block_q, 1) f32
+    l_ref,       # VMEM (block_q, 1) f32
     acc_ref,     # VMEM (block_q, D) f32
     *,
     causal: bool,
@@ -85,8 +88,8 @@ def _attn_kernel(
 
         q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
         kv_ids = kv_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
-        seg_q = seg_q_ref[0][:, None]   # (bq, 1)
-        seg_kv = seg_kv_ref[0][None, :]  # (1, bk)
+        seg_q = seg_q_ref[0]    # (bq, 1)
+        seg_kv = seg_kv_ref[0]  # (1, bk)
         mask = jnp.logical_and(seg_q == seg_kv, seg_kv != 0)
         if causal:
             mask = jnp.logical_and(mask, q_ids >= kv_ids)
@@ -95,13 +98,13 @@ def _attn_kernel(
         s = jnp.where(mask, s, _NEG_INF)
 
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         # fully-masked rows: s == m_new == NEG_INF would give p = 1
         p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1)
-        acc_ref[...] = alpha[:, None] * acc_ref[...] + jax.lax.dot_general(
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -110,7 +113,7 @@ def _attn_kernel(
     @pl.when(ik == n_kv - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -154,8 +157,8 @@ def packed_flash_attention(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b, h, iq, ik: (b, iq)),
-            pl.BlockSpec((1, block_kv), lambda b, h, iq, ik: (b, ik)),
+            pl.BlockSpec((1, block_q, 1), lambda b, h, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda b, h, iq, ik: (b, 0, ik)),
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_kv, D), lambda b, h, iq, ik: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, block_kv, D), lambda b, h, iq, ik: (b, h, ik, 0)),
@@ -165,9 +168,9 @@ def packed_flash_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
-    )(segment_ids_q, segment_ids_kv, q, k, v)
+    )(segment_ids_q[:, :, None], segment_ids_kv[:, None, :], q, k, v)

@@ -1,8 +1,9 @@
 """Jit'd public wrapper for packed attention.
 
 Accepts model-layout tensors (B, S, H, D) with separate KV heads, handles
-GQA repetition and layout transposes, and dispatches to the Pallas kernel on
-TPU or to its interpret-mode execution elsewhere (CPU tests).
+GQA repetition and layout transposes, and runs the Pallas kernel (compiled on
+a TPU, interpreted only when asked; see ``kernels.dispatch``) or the jnp
+reference.
 """
 
 from __future__ import annotations
@@ -12,14 +13,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..dispatch import pallas_interpret
 from .kernel import packed_flash_attention
 from .ref import packed_attention_ref
 
 __all__ = ["packed_attention"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -66,7 +64,7 @@ def packed_attention(
         out = packed_flash_attention(
             qt, kt, vt, sp_q, sp_kv,
             causal=causal, window=window,
-            interpret=interpret or not _on_tpu(),
+            interpret=pallas_interpret(interpret),
         )
     else:
         out = packed_attention_ref(

@@ -11,6 +11,10 @@ TPU-native structure:
     page indirection: the K/V block for grid step (b, h, i) is DMA'd from
     HBM page ``page_table[b, i]`` while the previous block computes —
     the TPU version of vLLM's gather;
+  - the pool is laid out (num_pages, KVH, page_size, D), so one block is
+    one KV head of one page and its last two dimensions are the
+    (page_size, D) tile the TPU compiler requires (page_size a multiple of
+    8 for f32, 16 for bf16);
   - grid = (B, KVH, max_pages); the page loop is the minor (sequential)
     dimension, so the online-softmax state (m, l, acc) for the G = H/KVH
     grouped query heads lives in VMEM scratch across the sweep;
@@ -40,11 +44,11 @@ def _paged_attn_kernel(
     page_table_ref,  # scalar-prefetch (B, max_pages) int32
     seq_lens_ref,    # scalar-prefetch (B,) int32
     q_ref,           # (1, 1, G, D)
-    k_ref,           # (1, page_size, 1, D)  page pt[b, i]
-    v_ref,           # (1, page_size, 1, D)
+    k_ref,           # (1, 1, page_size, D)  page pt[b, i], head h
+    v_ref,           # (1, 1, page_size, D)
     o_ref,           # (1, 1, G, D)
-    m_ref,           # VMEM (G,) f32
-    l_ref,           # VMEM (G,) f32
+    m_ref,           # VMEM (G, 1) f32
+    l_ref,           # VMEM (G, 1) f32
     acc_ref,         # VMEM (G, D) f32
     *,
     page_size: int,
@@ -68,8 +72,8 @@ def _paged_attn_kernel(
     @pl.when(in_use)
     def _compute():
         q = q_ref[0, 0]        # (G, D)
-        k = k_ref[0, :, 0]     # (page_size, D)
-        v = v_ref[0, :, 0]     # (page_size, D)
+        k = k_ref[0, 0]        # (page_size, D)
+        v = v_ref[0, 0]        # (page_size, D)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -82,12 +86,12 @@ def _paged_attn_kernel(
         s = jnp.where(mask, s, _NEG_INF)
 
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1)
-        acc_ref[...] = alpha[:, None] * acc_ref[...] + jax.lax.dot_general(
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -96,21 +100,21 @@ def _paged_attn_kernel(
     @pl.when(i == n_pages - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(
     q: jax.Array,           # (B, H, D) one query token per sequence
-    k_pool: jax.Array,      # (num_pages, page_size, KVH, D)
-    v_pool: jax.Array,      # (num_pages, page_size, KVH, D)
+    k_pool: jax.Array,      # (num_pages, KVH, page_size, D)
+    v_pool: jax.Array,      # (num_pages, KVH, page_size, D)
     page_table: jax.Array,  # (B, max_pages) int32, -1 = unused slot
     seq_lens: jax.Array,    # (B,) int32
     *,
     interpret: bool = False,
 ) -> jax.Array:
     B, H, D = q.shape
-    num_pages, page_size, KVH, _ = k_pool.shape
+    num_pages, KVH, page_size, _ = k_pool.shape
     G = H // KVH
     max_pages = page_table.shape[1]
     scale = 1.0 / math.sqrt(D)
@@ -131,20 +135,20 @@ def paged_decode_attention(
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, i, pt, sl: (b, h, 0, 0)),
             pl.BlockSpec(
-                (1, page_size, 1, D),
-                lambda b, h, i, pt, sl: (pt[b, i], 0, h, 0),
+                (1, 1, page_size, D),
+                lambda b, h, i, pt, sl: (pt[b, i], h, 0, 0),
             ),
             pl.BlockSpec(
-                (1, page_size, 1, D),
-                lambda b, h, i, pt, sl: (pt[b, i], 0, h, 0),
+                (1, 1, page_size, D),
+                lambda b, h, i, pt, sl: (pt[b, i], h, 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, G, D), lambda b, h, i, pt, sl: (b, h, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
     )

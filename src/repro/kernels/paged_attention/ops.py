@@ -1,7 +1,8 @@
 """Jit'd public wrapper for paged decode attention.
 
 Bridges the host-side ``PageAllocator`` (First-Fit page tables as numpy) and
-the device kernel, and dispatches kernel vs interpret vs jnp-reference.
+the device kernel, and runs the kernel (compiled on a TPU, interpreted only
+when asked; see ``kernels.dispatch``) or the jnp reference.
 """
 
 from __future__ import annotations
@@ -13,14 +14,11 @@ import jax
 import jax.numpy as jnp
 
 from ...serving.kv_cache import PageAllocator
+from ..dispatch import pallas_interpret
 from .kernel import paged_decode_attention
 from .ref import paged_attention_ref
 
 __all__ = ["paged_attention", "page_table_from_allocator"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def page_table_from_allocator(
@@ -37,8 +35,8 @@ def page_table_from_allocator(
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def paged_attention(
     q: jax.Array,           # (B, H, D)
-    k_pool: jax.Array,      # (num_pages, page_size, KVH, D)
-    v_pool: jax.Array,      # (num_pages, page_size, KVH, D)
+    k_pool: jax.Array,      # (num_pages, KVH, page_size, D)
+    v_pool: jax.Array,      # (num_pages, KVH, page_size, D)
     page_table: jax.Array,  # (B, max_pages) int32, -1 = unused
     seq_lens: jax.Array,    # (B,)
     *,
@@ -48,6 +46,6 @@ def paged_attention(
     if use_kernel:
         return paged_decode_attention(
             q, k_pool, v_pool, page_table, seq_lens,
-            interpret=interpret or not _on_tpu(),
+            interpret=pallas_interpret(interpret),
         )
     return paged_attention_ref(q, k_pool, v_pool, page_table, seq_lens)

@@ -15,7 +15,7 @@ __all__ = ["paged_attention_ref", "gather_pages"]
 
 
 def gather_pages(
-    pool: jax.Array,        # (num_pages, page_size, KVH, D)
+    pool: jax.Array,        # (num_pages, KVH, page_size, D)
     page_table: jax.Array,  # (B, max_pages) int32, -1 = unused
 ) -> jax.Array:
     """Dense (B, max_pages * page_size, KVH, D) view of the paged cache.
@@ -24,20 +24,20 @@ def gather_pages(
     the garbage never contributes.
     """
     idx = jnp.maximum(page_table, 0)                       # (B, P)
-    gathered = pool[idx]                                   # (B, P, ps, KVH, D)
-    B, P, ps, KVH, D = gathered.shape
-    return gathered.reshape(B, P * ps, KVH, D)
+    gathered = pool[idx]                                   # (B, P, KVH, ps, D)
+    B, P, KVH, ps, D = gathered.shape
+    return gathered.transpose(0, 1, 3, 2, 4).reshape(B, P * ps, KVH, D)
 
 
 def paged_attention_ref(
     q: jax.Array,           # (B, H, D) one query token per sequence
-    k_pool: jax.Array,      # (num_pages, page_size, KVH, D)
-    v_pool: jax.Array,      # (num_pages, page_size, KVH, D)
+    k_pool: jax.Array,      # (num_pages, KVH, page_size, D)
+    v_pool: jax.Array,      # (num_pages, KVH, page_size, D)
     page_table: jax.Array,  # (B, max_pages) int32, -1 = unused
     seq_lens: jax.Array,    # (B,) valid tokens per sequence
 ) -> jax.Array:
     B, H, D = q.shape
-    KVH = k_pool.shape[2]
+    KVH = k_pool.shape[1]
     G = H // KVH
     scale = 1.0 / math.sqrt(D)
 

@@ -17,7 +17,8 @@ __all__ = [
     "cost_summary",
     "memory_summary",
     "DTYPE_BYTES",
-    "HW",
+    "PEAKS",
+    "peaks",
 ]
 
 DTYPE_BYTES = {
@@ -30,14 +31,32 @@ DTYPE_BYTES = {
     "c128": 16,
 }
 
-# TPU v5e hardware constants (per chip) — the roofline denominators.
-HW = {
-    "peak_flops_bf16": 197e12,   # FLOP/s
-    "hbm_bw": 819e9,             # B/s
-    "ici_bw": 50e9,              # B/s per link (~3D torus links)
-    "dcn_bw": 6.25e9,            # B/s per chip across pods (25 GB/s / host)
-    "hbm_bytes": 16e9,
+# Per-chip roofline denominators, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB
+# of HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect per chip
+# (four links, so 50 GB/s each).  ``dcn_bw`` is not in that table: it is
+# this repo's assumption of 25 GB/s per host shared by four chips.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,   # FLOP/s
+        "hbm_bw": 819e9,             # B/s
+        "ici_bw": 50e9,              # B/s per link
+        "dcn_bw": 6.25e9,            # B/s per chip across pods (assumed)
+        "hbm_bytes": 16e9,
+    },
 }
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peaks of one chip of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _COLLECTIVE_OPS = (

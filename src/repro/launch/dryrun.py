@@ -1,4 +1,7 @@
 import os
+# A host-side projection: 512 virtual CPU devices stand in for the pods, so
+# the CPU platform is pinned even on a machine that has a TPU attached.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
@@ -39,11 +42,13 @@ from ..distributed.sharding import (
 from ..models import abstract_params, build_model, cache_specs, input_specs
 from ..models.params import Spec, tree_bytes
 from ..training import OptimizerConfig, make_train_step
-from .analysis import HW, cost_summary, memory_summary
+from .analysis import cost_summary, memory_summary, peaks
 from .hlo_analysis import analyze_hlo_text
 from .mesh import make_production_mesh
 
 PER_POD_CHIPS = 256
+# the chip the projected 16x16 pods are made of
+HW = peaks("TPU v5 lite")
 
 
 def _abstract_opt_state(param_specs_tree: Any) -> Any:
@@ -108,7 +113,7 @@ def lower_cell(
     elif shape.kind == "prefill":
         params = abstract_params(specs, dtype=jnp.bfloat16)
         jitted = jax.jit(
-            lambda p, b: model.prefill(p, b),
+            lambda p, b: model.prefill(p, b, max_len=shape.seq_len),
             in_shardings=(p_shard, b_shard),
         )
         with mesh, activation_sharding(mesh, rules):

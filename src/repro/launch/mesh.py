@@ -8,9 +8,21 @@ initialization, while smoke tests and benchmarks see the real single device.
 
 from __future__ import annotations
 
-import jax
+from typing import Sequence
 
-__all__ = ["make_production_mesh", "make_local_mesh"]
+import jax
+from jax.sharding import AxisType
+
+__all__ = ["make_mesh", "make_production_mesh", "make_local_mesh"]
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """A mesh whose axes are all ``Auto``: sharding is propagated by XLA from
+    the ``with_sharding_constraint`` hints in the model code (an ``Explicit``
+    axis, ``jax.make_mesh``'s default, refuses those hints)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,10 +33,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh():
     """All local devices on the data axis (CPU smoke / small runs)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))

@@ -1,4 +1,7 @@
 import os
+# A host-side projection: 512 virtual CPU devices stand in for the pods, so
+# the CPU platform is pinned even on a machine that has a TPU attached.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")

@@ -3,7 +3,8 @@
 Runs the IRM-scheduled continuous-batching engine against either the
 discrete-time simulated backend (capacity planning / control-plane soak,
 ``--backend sim``) or a real model executing prefill + decode on the local
-devices (``--backend local``, reduced config on CPU).
+device (``--backend local``: a TPU at full width, or a reduced config on a
+CPU with ``--smoke``).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --backend sim --requests 500
@@ -15,11 +16,13 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Dict
 
 import numpy as np
 
 from ..configs import ARCH_NAMES, get_config
 from ..serving import EngineConfig, ReplicaConfig, Request, ServingEngine
+from .compile_cache import enable_compilation_cache
 
 
 def run_sim(args: argparse.Namespace) -> None:
@@ -44,7 +47,16 @@ def run_sim(args: argparse.Namespace) -> None:
           f"p99 {s['p99_latency']:.2f}s  peak replicas {s['peak_replicas']}")
 
 
-def run_local(args: argparse.Namespace) -> None:
+def run_local(args: argparse.Namespace) -> Dict[str, Any]:
+    """Prefill a batch of 16-token prompts, then decode greedily on the
+    local device.
+
+    Prints the compile seconds (timed apart from the run), the prefill
+    seconds and the decode tokens per second, each labelled with the
+    device.  Returns the prefill logits, the generated tokens (B, 1 + gen)
+    and each decode step's logits (B, gen, V), with the parameters and
+    batch they came from (for a reference to compare with).
+    """
     import jax
     import jax.numpy as jnp
 
@@ -78,17 +90,44 @@ def run_local(args: argparse.Namespace) -> None:
             rng.normal(size=(B, cfg.frontend_tokens, cfg.d_model)) * 0.02,
             jnp.float32)
 
-    t0 = time.perf_counter()
-    logits, cache = model.prefill(params, batch)
+    prefill = jax.jit(
+        lambda p, b: model.prefill(p, b, max_len=prompt_len + gen))
     decode = jax.jit(model.decode_step, donate_argnums=(2,))
+
+    t0 = time.perf_counter()
+    prefill_c = prefill.lower(params, batch).compile()
+    _, cache_shape = jax.eval_shape(prefill, params, batch)
+    tok_shape = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    decode_c = decode.lower(params, {"tokens": tok_shape}, cache_shape).compile()
+    compile_s = time.perf_counter() - t0
+
+    t0 = time.perf_counter()
+    logits, cache = prefill_c(params, batch)
     toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+    toks.block_until_ready()
+    prefill_s = time.perf_counter() - t0
+    generated, step_logits = [toks], []
+    t0 = time.perf_counter()
     for _ in range(gen):
-        logits, cache = decode(params, {"tokens": toks}, cache)
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+        logits_t, cache = decode_c(params, {"tokens": toks}, cache)
+        toks = jnp.argmax(logits_t, axis=-1).astype(jnp.int32)[:, None]
+        generated.append(toks)
+        step_logits.append(logits_t)
     jax.block_until_ready(toks)
-    dt = time.perf_counter() - t0
-    print(f"served {B} sequences x {gen} tokens in {dt:.2f}s "
-          f"({B * gen / dt:.1f} tok/s on {jax.default_backend()})")
+    decode_s = time.perf_counter() - t0
+
+    dev = jax.devices()[0]
+    device = f"{dev.platform}:{dev.device_kind}"
+    print(f"[{device}] compile {compile_s:.2f} s (prefill + decode)")
+    print(f"[{device}] served {B} sequences x {gen} tokens: prefill "
+          f"{prefill_s:.3f} s, decode {B * gen / decode_s:.1f} tok/s")
+    return {
+        "prefill_logits": np.asarray(logits),
+        "tokens": np.asarray(jnp.concatenate(generated, axis=1)),
+        "decode_logits": np.asarray(jnp.stack(step_logits, axis=1)),
+        "params": params,
+        "batch": batch,
+    }
 
 
 def main() -> None:
@@ -105,6 +144,7 @@ def main() -> None:
     if args.backend == "sim":
         run_sim(args)
     else:
+        enable_compilation_cache()
         run_local(args)
 
 

@@ -66,8 +66,8 @@ class EncDecLM:
 
         def stack(n: int, tree: Any) -> Any:
             return jax.tree.map(
-                lambda s: Spec((n,) + s.shape, ("layers",) + s.axes,
-                               init=s.init, scale=s.scale, dtype=s.dtype),
+                lambda s: dataclasses.replace(
+                    s, shape=(n,) + s.shape, axes=("layers",) + s.axes),
                 tree,
                 is_leaf=lambda x: isinstance(x, Spec),
             )
@@ -172,8 +172,15 @@ class EncDecLM:
         return loss, dict(metrics, loss=loss)
 
     def prefill(
-        self, params: Dict[str, Any], batch: Dict[str, jax.Array]
+        self, params: Dict[str, Any], batch: Dict[str, jax.Array], *,
+        max_len: int,
     ) -> Tuple[jax.Array, Dict[str, Any]]:
+        """Returns (last-token logits (B, V), cache pytree) with self-attention
+        K/V room for ``max_len`` decoder positions, as ``init_cache`` lays
+        them out."""
+        S = batch["segment_ids"].shape[1]
+        if max_len < S:
+            raise ValueError(f"max_len {max_len} < prompt length {S}")
         enc_out = self.encode(
             params, batch["enc_embeds"], batch["enc_segment_ids"],
             remat_policy=None,
@@ -183,6 +190,9 @@ class EncDecLM:
             enc_out, batch["enc_segment_ids"],
             remat_policy=None, collect_cache=True,
         )
+        room = [(0, 0), (0, 0), (0, max_len - S), (0, 0), (0, 0)]
+        caches = dict(caches, k=jnp.pad(caches["k"], room),
+                      v=jnp.pad(caches["v"], room))  # (layers, B, S, KVH, D)
         seg = batch["segment_ids"]
         last = jnp.maximum(jnp.sum((seg > 0).astype(jnp.int32), axis=1) - 1, 0)
         x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]

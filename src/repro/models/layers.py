@@ -354,14 +354,8 @@ def decode_attention_distributed(
     Returns None when no mesh context is active or the layout doesn't
     shard the cache sequence (callers fall back to the dense path).
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    try:  # jax >= 0.6: top-level export, replication check named check_vma
-        from jax import shard_map
-        _sm_kwargs = {"check_vma": False}
-    except ImportError:  # jax 0.4/0.5: experimental path, check_rep
-        from jax.experimental.shard_map import shard_map
-        _sm_kwargs = {"check_rep": False}
 
     from ..distributed.context import _STATE  # same-module convention
 
@@ -403,7 +397,7 @@ def decode_attention_distributed(
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, len_spec),
         out_specs=q_spec,
-        **_sm_kwargs,
+        check_vma=False,
     )(q, k_cache, v_cache, jnp.asarray(cache_len).reshape(B))
 
 
@@ -452,10 +446,14 @@ def attention_specs(cfg: Any, cross: bool = False) -> Dict[str, Any]:
     d, hd = cfg.d_model, cfg.head_dim_
     H, KVH = cfg.n_heads, cfg.n_kv_heads
     specs: Dict[str, Any] = {
-        "wq": Spec((d, H, hd), ("embed", "heads", "head_dim"), init="scaled"),
-        "wk": Spec((d, KVH, hd), ("embed", "kv_heads", "head_dim"), init="scaled"),
-        "wv": Spec((d, KVH, hd), ("embed", "kv_heads", "head_dim"), init="scaled"),
-        "wo": Spec((H, hd, d), ("heads", "head_dim", "embed"), init="scaled"),
+        "wq": Spec((d, H, hd), ("embed", "heads", "head_dim"), init="scaled",
+                   fan_in=d),
+        "wk": Spec((d, KVH, hd), ("embed", "kv_heads", "head_dim"),
+                   init="scaled", fan_in=d),
+        "wv": Spec((d, KVH, hd), ("embed", "kv_heads", "head_dim"),
+                   init="scaled", fan_in=d),
+        "wo": Spec((H, hd, d), ("heads", "head_dim", "embed"), init="scaled",
+                   fan_in=H * hd),
     }
     if cfg.qkv_bias:
         specs["bq"] = Spec((H, hd), ("heads", "head_dim"), init="zeros")

@@ -39,6 +39,9 @@ class Spec:
     init: str = "normal"  # normal | zeros | ones | scaled (fan-in)
     scale: float = 1.0
     dtype: Any = jnp.float32
+    # inputs each output sums over, for init="scaled"; None: shape[-2]
+    # (right for an (in, out) matrix, wrong for e.g. (d, heads, head_dim))
+    fan_in: Optional[int] = None
 
     def __post_init__(self) -> None:
         if len(self.shape) != len(self.axes):
@@ -55,7 +58,9 @@ def _init_one(key: jax.Array, spec: Spec, dtype: Any) -> jax.Array:
     if spec.init == "normal":
         return (spec.scale * jax.random.normal(key, spec.shape)).astype(dtype)
     if spec.init == "scaled":  # fan-in scaled (truncated-normal-ish)
-        fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
+        fan_in = spec.fan_in or (
+            spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
+        )
         std = spec.scale / math.sqrt(max(1, fan_in))
         return (std * jax.random.normal(key, spec.shape)).astype(dtype)
     raise ValueError(f"unknown init {spec.init!r}")

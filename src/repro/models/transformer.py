@@ -139,12 +139,8 @@ def _stack_period(cfg: Any, spec_tree: Any) -> Any:
     n = cfg.n_periods
 
     def stack(s: Spec) -> Spec:
-        return Spec(
-            shape=(n,) + s.shape,
-            axes=("layers",) + s.axes,
-            init=s.init,
-            scale=s.scale,
-            dtype=s.dtype,
+        return dataclasses.replace(
+            s, shape=(n,) + s.shape, axes=("layers",) + s.axes
         )
 
     return jax.tree.map(stack, spec_tree, is_leaf=lambda x: isinstance(x, Spec))
@@ -282,14 +278,22 @@ class DecoderLM:
 
     # ---- serving: prefill -------------------------------------------------------
     def prefill(
-        self, params: Dict[str, Any], batch: Dict[str, jax.Array]
+        self, params: Dict[str, Any], batch: Dict[str, jax.Array], *,
+        max_len: int,
     ) -> Tuple[jax.Array, Dict[str, Any]]:
-        """Returns (last-token logits (B, V), cache pytree)."""
+        """Returns (last-token logits (B, V), cache pytree).
+
+        The K/V caches hold ``max_len`` positions, the prompt's first, as
+        ``init_cache(B, max_len)`` lays them out: decode writes past the
+        end of an array would be dropped."""
         cfg = self.cfg
         x = self._embed(params, batch)
         seg = batch["segment_ids"]
         pos_ids = batch["positions"]
         B, S = seg.shape
+        if max_len < S:
+            raise ValueError(f"max_len {max_len} < prompt length {S}")
+        room = [(0, 0), (0, max_len - S), (0, 0), (0, 0)]  # (B, S, KVH, D)
 
         def period_body(x, period_params):
             caches = {}
@@ -299,7 +303,8 @@ class DecoderLM:
                 h = norm(p["ln1"], cfg.norm_type, x)
                 if char == "A":
                     out, (k, v) = attention(p["mixer"], cfg, h, seg, pos_ids)
-                    caches[str(pos)] = {"k": k, "v": v}
+                    caches[str(pos)] = {"k": jnp.pad(k, room),
+                                        "v": jnp.pad(v, room)}
                 elif char == "M":
                     out, st = ssm_lib.mamba_forward(p["mixer"], cfg, h)
                     caches[str(pos)] = st

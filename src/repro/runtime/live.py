@@ -213,13 +213,6 @@ async def _drive(
     stats: Optional[Dict[str, object]],
     bus=None,
 ) -> SimResult:
-    clock = ScaledClock(rt.time_scale)
-    total = stream.num_messages
-    master = Master(total_expected=total, bus=bus)
-    # construct the payload before starting the clock: JaxPayload warms the
-    # jit cache at init, and that wall time must not burn virtual time
-    payload = make_payload(rt.payload, **rt.payload_kwargs)
-    poll = rt.poll_interval if rt.poll_interval is not None else cfg.dt
     if rt.measurement not in ("emulated", "os"):
         raise ValueError(
             f"measurement must be 'emulated' or 'os', got {rt.measurement!r}"
@@ -234,8 +227,16 @@ async def _drive(
         )
     transport = make_transport(rt.transport, **tkwargs)
     if hasattr(transport, "set_payload_spec"):
-        # process-backed workers build their own payload instance
+        # process-backed workers build their own payload instance; the
+        # transport refuses a device payload before anything is built
         transport.set_payload_spec(rt.payload, rt.payload_kwargs)
+    clock = ScaledClock(rt.time_scale)
+    total = stream.num_messages
+    master = Master(total_expected=total, bus=bus)
+    # construct the payload before starting the clock: JaxPayload warms the
+    # jit cache at init, and that wall time must not burn virtual time
+    payload = make_payload(rt.payload, **rt.payload_kwargs)
+    poll = rt.poll_interval if rt.poll_interval is not None else cfg.dt
     pool = WorkerPool(cfg, master, clock, payload, poll_interval=poll,
                       transport=transport)
     lifecycle = Lifecycle(pool, cfg, clock)

@@ -8,11 +8,16 @@ Two built-ins:
   ``msg.duration`` scenario seconds, so service times mirror the stream
   generator's distributions and the live runtime's scheduling dynamics are
   directly comparable to the discrete-event simulator.
-- ``jax`` — runs a real repro kernel (the grouped-matmul reference path,
-  which executes on CPU) in a worker thread per message, then pads with a
-  calibrated sleep up to ``msg.duration``.  This exercises genuine
-  serialization/compute interleaving on the event loop: the master keeps
-  brokering and the IRM keeps packing while XLA crunches.
+- ``jax`` — runs the grouped-matmul Pallas kernel on the accelerator (a
+  TPU; on a CPU only with ``interpret=True``, see ``kernels.dispatch``) in
+  a worker thread per message, then pads with a calibrated sleep up to
+  ``msg.duration``.  This exercises genuine dispatch/compute interleaving
+  on the event loop: the master keeps brokering and the IRM keeps packing
+  while the device computes.
+
+A payload with ``on_device = True`` holds the accelerator, which belongs to
+one process at a time, so it runs only in the process that owns the chip:
+process-backed transports refuse it (``MultiprocTransport``).
 
 Payloads resolve by name through ``make_payload`` so scenarios/CLI can
 select them (``--payload jax``), mirroring ``core.binpack.make_packer``.
@@ -32,11 +37,18 @@ from .annotations import worker_side
 
 __all__ = ["SleepPayload", "JaxPayload", "make_payload", "PAYLOADS"]
 
+# Largest |kernel - reference| allowed at warm-up, as a fraction of
+# max |reference|.  The kernel takes f32 operands; if the MXU rounds them
+# to bf16 (8-bit mantissa) the error over a 2048-long contraction is about
+# 2e-3 of the largest output, so 2e-2 leaves a tenfold margin.
+GMM_CHECK_RTOL = 2e-2
+
 
 class SleepPayload:
     """Occupy the PE for ``msg.duration`` scenario seconds (timed wait)."""
 
     name = "sleep"
+    on_device = False
 
     async def __call__(self, msg, clock) -> None:
         await clock.sleep(msg.duration)
@@ -49,19 +61,23 @@ class SleepPayload:
 
 
 class JaxPayload:
-    """Run a real JAX kernel per message, padded to ``msg.duration``.
+    """Run the grouped-matmul kernel per message, padded to ``msg.duration``.
 
-    Each message triggers one grouped-matmul (``kernels.grouped_matmul.gmm``
-    on its jnp reference path, so it runs on CPU without a TPU) in a thread
-    executor — the event loop, master broker, and IRM stay live while the
-    computation runs — then sleeps whatever remains of the message's
-    scenario-time duration so the *schedule* stays calibrated to the
-    stream's service-time distribution regardless of host speed.
+    Each message triggers one ``kernels.grouped_matmul.gmm`` call with
+    ``use_kernel=True`` (compiled on a TPU; on a CPU only when constructed
+    with ``interpret=True``) in a thread executor — the event loop, master
+    broker, and IRM stay live while the device computes — then sleeps
+    whatever remains of the message's scenario-time duration so the
+    *schedule* stays calibrated to the stream's service-time distribution
+    regardless of device speed.  The warm-up call is checked against the
+    jnp reference (``kernels/grouped_matmul/ref.py``).
     """
 
     name = "jax"
+    on_device = True
 
-    def __init__(self, experts: int = 4, rows: int = 64, dim: int = 64):
+    def __init__(self, experts: int = 4, rows: int = 64, dim: int = 64,
+                 interpret: bool = False):
         # Import here so the live runtime stays usable without jax installed
         # (the sleep payload has no such dependency).
         import jax.numpy as jnp
@@ -70,6 +86,7 @@ class JaxPayload:
         from ..kernels.grouped_matmul.ops import gmm
 
         self._gmm = gmm
+        self._interpret = interpret
         rng = np.random.default_rng(0)
         self._x = jnp.asarray(
             rng.standard_normal((experts, rows, dim)), jnp.float32
@@ -78,11 +95,32 @@ class JaxPayload:
             rng.standard_normal((experts, dim, dim)), jnp.float32
         )
         self._sizes = jnp.full((experts,), rows, jnp.int32)
-        self._compute()  # warm the jit cache outside any message's budget
+        # warm the jit cache outside any message's budget
+        self.check_error = self._check(self._compute())
 
     @worker_side
-    def _compute(self) -> None:
-        self._gmm(self._x, self._w, self._sizes, use_kernel=False).block_until_ready()
+    def _compute(self):
+        return self._gmm(self._x, self._w, self._sizes, use_kernel=True,
+                         interpret=self._interpret).block_until_ready()
+
+    def _check(self, out) -> float:
+        """Scale-normalized error of ``out`` against the f32 reference;
+        raises when it exceeds ``GMM_CHECK_RTOL``."""
+        import jax
+        import numpy as np
+
+        from ..kernels.grouped_matmul.ref import grouped_matmul_ref
+
+        with jax.default_matmul_precision("highest"):
+            ref = np.asarray(grouped_matmul_ref(self._x, self._w, self._sizes))
+        err = float(np.abs(np.asarray(out) - ref).max()
+                    / max(np.abs(ref).max(), 1e-30))
+        if not err <= GMM_CHECK_RTOL:
+            raise RuntimeError(
+                f"grouped-matmul kernel disagrees with its reference: "
+                f"max error {err:.3g} of max |ref| > {GMM_CHECK_RTOL}"
+            )
+        return err
 
     async def __call__(self, msg, clock) -> None:
         loop = asyncio.get_running_loop()

@@ -64,7 +64,7 @@ def measure_workers(
     for w in workers:
         if w.state is not WorkerState.ACTIVE:
             continue
-        acc, counts = w.probe.accumulators()
+        acc = w.probe.samples()
         if multi:
             totals = np.zeros(D)
             for pe in w.pes:
@@ -86,11 +86,9 @@ def measure_workers(
                 if accumulate:
                     img = pe.image
                     if img in acc:
-                        acc[img] = acc[img] + vec
-                        counts[img] += 1
+                        acc[img].append(vec)
                     else:
-                        acc[img] = vec
-                        counts[img] = 1
+                        acc[img] = [vec]
             clipped = np.minimum(totals, 1.0)
             dim_out[w.idx] = clipped
             out[w.idx] = clipped[0]
@@ -111,11 +109,9 @@ def measure_workers(
                 if accumulate:
                     img = pe.image
                     if img in acc:
-                        acc[img] += draw / cores_per_worker
-                        counts[img] += 1
+                        acc[img].append(draw / cores_per_worker)
                     else:
-                        acc[img] = draw / cores_per_worker
-                        counts[img] = 1
+                        acc[img] = [draw / cores_per_worker]
             u = cores / cores_per_worker
             out[w.idx] = u if u < 1.0 else 1.0
     return out, dim_out

@@ -57,6 +57,7 @@ import asyncio
 import os
 import pickle
 import queue
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -71,7 +72,22 @@ __all__ = [
     "MultiprocTransport",
     "make_transport",
     "TRANSPORTS",
+    "worker_start_method",
 ]
+
+
+def worker_start_method() -> str:
+    """How to start worker processes: ``fork`` while no JAX backend is live
+    here, ``spawn`` once one is.  A forked child would inherit the parent's
+    device client (on a TPU host, the chip) and its threads mid-flight; a
+    spawned one starts clean.  Reads JAX's state without importing it."""
+    import multiprocessing as mp
+
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    if (bridge is not None and bridge.backends_are_initialized()) \
+            or "fork" not in mp.get_all_start_methods():
+        return "spawn"
+    return "fork"
 
 
 class Transport:
@@ -436,9 +452,7 @@ class MultiprocTransport(Transport):
         import multiprocessing as mp
 
         if start_method is None:
-            start_method = (
-                "fork" if "fork" in mp.get_all_start_methods() else "spawn"
-            )
+            start_method = worker_start_method()
         self._ctx = mp.get_context(start_method)
         self.start_method = start_method
         self.poll_wall = float(poll_wall)
@@ -467,7 +481,20 @@ class MultiprocTransport(Transport):
 
     # ---- provisioning ------------------------------------------------------
     def set_payload_spec(self, name: str, kwargs: dict) -> None:
-        """What each worker process should construct as its PE payload."""
+        """What each worker process should construct as its PE payload.
+
+        Refuses a device payload: the accelerator belongs to one process,
+        and every worker process would build its own copy.
+        """
+        from .payloads import PAYLOADS
+
+        if getattr(PAYLOADS.get(name), "on_device", False):
+            raise ValueError(
+                f"payload {name!r} runs on the accelerator, which only one "
+                "process may hold; the multiproc transport would build it in "
+                "every worker process.  Run it in-process (backend 'live', "
+                "transport 'inproc')."
+            )
         self._payload_spec = (name, dict(kwargs))
 
     def connect(self) -> None:
@@ -666,7 +693,7 @@ class MultiprocTransport(Transport):
         self._real_core_s += cpu_s
         self._emulated_core_s += msg.cpu_cores * busy_wall
         if self.measurement == "os":
-            acc, counts = worker.probe.accumulators()
+            acc = worker.probe.samples()
             dims = pool._dims
             if len(dims) > 1:
                 import numpy as np
@@ -680,11 +707,9 @@ class MultiprocTransport(Transport):
             else:
                 sample = min(real_frac, 1.0)
             if pe.image in acc:
-                acc[pe.image] = acc[pe.image] + sample
-                counts[pe.image] += 1
+                acc[pe.image].append(sample)
             else:
-                acc[pe.image] = sample
-                counts[pe.image] = 1
+                acc[pe.image] = [sample]
 
     # ---- failure injection -------------------------------------------------
     @loop_only(blocking=(

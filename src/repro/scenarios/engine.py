@@ -313,7 +313,10 @@ def sweep_policies(
     ``jobs`` caps worker processes (default: ``min(len(policies), cpus)``);
     ``jobs=1`` — or an unregistered ad-hoc ``Scenario`` object, which cannot
     be re-resolved inside a child process — falls back to the serial loop.
-    Results keep the order of ``policies``.
+    Results keep the order of ``policies``.  A parallel sweep over a device
+    payload (``--payload jax``) is refused: the accelerator belongs to one
+    process.  Workers are forked only while no JAX backend is live here,
+    and spawned otherwise.
     """
     policies = list(policies)
     for p in policies:
@@ -334,11 +337,23 @@ def sweep_policies(
         return {p: run_scenario(scn, policy=p, **kwargs) for p in policies}
 
     import concurrent.futures as cf
+    import multiprocessing as mp
     from concurrent.futures.process import BrokenProcessPool
 
+    from ..runtime.payloads import PAYLOADS
+    from ..runtime.transport import worker_start_method
+
+    payload = getattr(runtime, "payload", "sleep")
+    if getattr(PAYLOADS.get(payload), "on_device", False):
+        raise ValueError(
+            f"a parallel sweep would build payload {payload!r} in every "
+            "worker process, but the accelerator belongs to one process; "
+            "sweep with jobs=1"
+        )
     work = [(scn.name, p, kwargs) for p in policies]
+    ctx = mp.get_context(worker_start_method())
     try:
-        with cf.ProcessPoolExecutor(max_workers=jobs) as ex:
+        with cf.ProcessPoolExecutor(max_workers=jobs, mp_context=ctx) as ex:
             results = list(ex.map(_sweep_one, work))
     except (KeyError, BrokenProcessPool):
         # Under the spawn start method (macOS/Windows) a child only sees

@@ -32,7 +32,7 @@ Pytree = Any
 @dataclasses.dataclass
 class TrainControllerConfig:
     checkpoint_dir: str = "/tmp/repro_ckpt"
-    checkpoint_every: int = 50
+    checkpoint_every: int = 50  # 0: write no checkpoints
     async_checkpoint: bool = True
     step_ttl: int = 3
     straggler_factor: float = 3.0
@@ -129,7 +129,9 @@ class TrainController:
             self.profiler.observe("train_step", min(1.0, dt))
 
             step += 1
-            if step % cfg.checkpoint_every == 0 or step == num_steps:
+            if cfg.checkpoint_every and (
+                step % cfg.checkpoint_every == 0 or step == num_steps
+            ):
                 self.ckpt.save(
                     step,
                     {"p": params, "o": opt_state},

@@ -111,43 +111,55 @@ def test_one_train_step_updates_params(arch, built):
         assert jnp.all(jnp.isfinite(leaf))
 
 
-@pytest.mark.parametrize("arch", ARCH_NAMES)
-def test_prefill_decode_consistency(arch, built):
-    """decode_step after prefill continues the sequence the prefill built:
-    prefill logits of the full prompt == teacher-forced decode logits."""
-    cfg, model, params, _ = built(arch)
-    rng = np.random.default_rng(2)
-    prompt = jnp.asarray(
-        rng.integers(1, cfg.vocab_size, size=(1, 8)), jnp.int32
-    )
+def _prompt_batch(cfg, tokens):
+    """A one-row batch of ``tokens`` with the arch's fixed side inputs."""
+    T = tokens.shape[1]
     batch = {
-        "tokens": prompt,
-        "segment_ids": jnp.ones_like(prompt),
-        "positions": jnp.broadcast_to(jnp.arange(8, dtype=jnp.int32), (1, 8)),
+        "tokens": tokens,
+        "segment_ids": jnp.ones_like(tokens),
+        "positions": jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (1, T)),
     }
+    rng = np.random.default_rng(4)
     if cfg.encdec:
-        enc_len = 8
         batch["enc_embeds"] = jnp.asarray(
-            rng.normal(size=(1, enc_len, cfg.d_model)) * 0.02, jnp.float32
+            rng.normal(size=(1, 8, cfg.d_model)) * 0.02, jnp.float32
         )
-        batch["enc_segment_ids"] = jnp.ones((1, enc_len), jnp.int32)
+        batch["enc_segment_ids"] = jnp.ones((1, 8), jnp.int32)
     if cfg.frontend == "vision":
         batch["vision_embeds"] = jnp.asarray(
             rng.normal(size=(1, cfg.frontend_tokens, cfg.d_model)) * 0.02,
             jnp.float32,
         )
+    return batch
 
-    logits_p, cache = model.prefill(params, batch)
-    assert jnp.all(jnp.isfinite(logits_p))
 
-    next_tok = jnp.argmax(logits_p, axis=-1).astype(jnp.int32)[:, None]
-    logits_d, cache = model.decode_step(params, {"tokens": next_tok}, cache)
-    assert logits_d.shape == logits_p.shape
-    assert jnp.all(jnp.isfinite(logits_d))
-    # decoding a second token also works (cache round-trips)
-    tok2 = jnp.argmax(logits_d, axis=-1).astype(jnp.int32)[:, None]
-    logits_d2, _ = model.decode_step(params, {"tokens": tok2}, cache)
-    assert jnp.all(jnp.isfinite(logits_d2))
+@pytest.mark.parametrize("arch", ARCH_NAMES)
+def test_prefill_decode_consistency(arch, built):
+    """decode_step after prefill continues the sequence the prefill built:
+    each greedy decode step's logits equal the last-token logits of a
+    prefill over the prompt plus the tokens decoded so far.  Capacity-binned
+    MoE drops different tokens in a batch than one at a time, so those
+    archs are held to finite values only."""
+    cfg, model, params, _ = built(arch)
+    rng = np.random.default_rng(2)
+    T, gen = 8, 2
+    seq = jnp.asarray(rng.integers(1, cfg.vocab_size, size=(1, T)), jnp.int32)
+
+    logits, cache = model.prefill(params, _prompt_batch(cfg, seq),
+                                  max_len=T + gen)
+    assert jnp.all(jnp.isfinite(logits))
+    shape = logits.shape
+    for _ in range(gen):
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+        logits, cache = model.decode_step(params, {"tokens": tok}, cache)
+        assert logits.shape == shape
+        assert jnp.all(jnp.isfinite(logits))
+        seq = jnp.concatenate([seq, tok], axis=1)
+        if cfg.moe is None:
+            ref, _ = model.prefill(params, _prompt_batch(cfg, seq),
+                                   max_len=seq.shape[1])
+            err = jnp.abs(logits - ref).max() / jnp.abs(ref).max()
+            assert float(err) < 1e-4
 
 
 @pytest.mark.parametrize("arch", ["olmo-1b", "qwen3-8b", "xlstm-125m",
@@ -164,7 +176,7 @@ def test_decode_matches_prefill_teacher_forced(arch, built):
         "segment_ids": jnp.ones_like(prompt),
         "positions": jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (1, T)),
     }
-    logits_p, _ = model.prefill(params, batch)
+    logits_p, _ = model.prefill(params, batch, max_len=T)
 
     cache = model.init_cache(1, T + 2, dtype=jnp.float32)
     logits_d = None
@@ -247,3 +259,47 @@ def test_param_counts_match_materialized():
         # analytic count excludes norm scales and uses the unpadded vocab;
         # require agreement within 5%
         assert abs(n_real - n_analytic) / n_real < 0.05
+
+
+def test_attention_init_uses_true_fan_in():
+    """Each attention projection starts at std 1/sqrt(inputs summed): d_model
+    for q/k/v, heads x head_dim for the output.  Scaling by one of the
+    head axes instead saturates the softmax, and a random-weight model then
+    turns a 1e-6 change of its weights into a different answer."""
+    cfg = get_config("olmo-1b").smoke()
+    model = build_model(cfg)
+    params = init_params(model.param_specs(), jax.random.PRNGKey(0))
+    mixer = params["blocks"]["0"]["mixer"]
+    d, hd = cfg.d_model, cfg.head_dim_
+    for name, fan_in in (("wq", d), ("wk", d), ("wv", d),
+                         ("wo", cfg.n_heads * hd)):
+        std = float(jnp.std(mixer[name]))
+        assert std == pytest.approx(fan_in ** -0.5, rel=0.1), name
+
+
+def test_run_local_decode_matches_teacher_forced():
+    """The serving driver's greedy decode continues the prefill's cache:
+    every step's logits equal decode_step fed the prompt and the served
+    tokens one at a time from an empty cache."""
+    import argparse
+
+    from repro.launch.serve import run_local
+
+    gen = 4
+    out = run_local(argparse.Namespace(arch="olmo-1b", smoke=True,
+                                       requests=8, gen_tokens=gen))
+    model = build_model(get_config("olmo-1b").smoke())
+    prompt = np.asarray(out["batch"]["tokens"])
+    fed = np.concatenate([prompt, out["tokens"][:, :gen]], axis=1)
+    T = prompt.shape[1]
+    step = jax.jit(model.decode_step)
+    cache = model.init_cache(fed.shape[0], T + gen, dtype=jnp.float32)
+    ref = []
+    for t in range(T + gen):
+        logits, cache = step(out["params"], {"tokens": fed[:, t:t + 1]}, cache)
+        ref.append(np.asarray(logits))
+    np.testing.assert_allclose(ref[T - 1], out["prefill_logits"],
+                               rtol=1e-4, atol=1e-4)
+    assert out["decode_logits"].shape == (fed.shape[0], gen, ref[0].shape[-1])
+    np.testing.assert_allclose(out["decode_logits"], np.stack(ref[T:], axis=1),
+                               rtol=1e-4, atol=1e-4)

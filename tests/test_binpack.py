@@ -118,24 +118,29 @@ def test_firstfit_tree_equivalence(sizes):
 @given(sizes_strategy)
 @settings(max_examples=200, deadline=None)
 def test_quality_envelopes(sizes):
-    """lower_bound <= bins_used; First-Fit <= 1.7*OPT + 2 (via LB <= OPT)."""
+    """lower_bound <= bins_used, and bins_used <= R*LB + 2 on these inputs."""
     lb = lower_bound(sizes)
     for name in ("first-fit", "best-fit", "worst-fit", "next-fit"):
         packer = make_packer(name)
         res = packer.pack([Item(s) for s in sizes])
         assert res.num_bins >= lb
         ratio = ASYMPTOTIC_RATIO[name]
-        # LB <= OPT, so R*LB + c is a valid (weaker) upper envelope
+        # LB <= OPT makes R*LB + c tighter than the theorem's R*OPT + c:
+        # an envelope uniform random sizes meet, not a bound for every
+        # input (200 items of 0.51 take 200 First-Fit bins, LB 102)
         assert res.num_bins <= math.ceil(ratio * lb) + 2
 
 
 @given(sizes_strategy)
 @settings(max_examples=100, deadline=None)
 def test_ffd_no_worse_than_ff(sizes):
+    """FFD needs at most 11/9 of First-Fit's bins plus 6/9: FFD <= 11/9 OPT
+    + 6/9 (Dosa 2007) and OPT <= FF.  It can lose to FF outright, e.g.
+    [0.5, 0.25, 0.25, 0.25, 0.375, 0.375]: 2 bins under FF, 3 under FFD."""
     items = [Item(s) for s in sizes]
     ff = FirstFit().pack(list(items))
     ffd = FirstFitDecreasing().pack(list(items))
-    assert ffd.num_bins <= ff.num_bins
+    assert ffd.num_bins <= 11 / 9 * ff.num_bins + 6 / 9
     # all items assigned, nothing lost
     assert len(ffd.assignments) == len(sizes)
     total = sum(b.used for b in ffd.bins)

@@ -70,6 +70,20 @@ def test_cli_live_backend_smoke(capsys):
     assert "makespan_s" in out
 
 
+def test_cli_multiproc_refuses_device_payload(capsys):
+    """``--backend multiproc --payload jax`` fails fast and says why."""
+    assert run_cli("microscopy", "--smoke", "--backend", "multiproc",
+                   "--payload", "jax") == 2
+    assert "only one process may hold" in capsys.readouterr().err
+
+
+def test_cli_parallel_sweep_refuses_device_payload(capsys):
+    assert run_cli("microscopy", "--smoke", "--backend", "live",
+                   "--payload", "jax", "--policy", "first-fit,best-fit",
+                   "--jobs", "2") == 2
+    assert "accelerator belongs to one process" in capsys.readouterr().err
+
+
 def test_cli_unknown_scenario_exits_2(capsys):
     assert run_cli("no-such-scenario") == 2
     assert "unknown scenario" in capsys.readouterr().err

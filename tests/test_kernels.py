@@ -186,8 +186,8 @@ def test_paged_attention_kernel_vs_ref(H, KVH, dtype):
     num_pages, page_size = 48, 16
     lens = [37, 5, 100]
     q = jnp.asarray(rng.normal(size=(B, H, D)), dtype)
-    kp = jnp.asarray(rng.normal(size=(num_pages, page_size, KVH, D)), dtype)
-    vp = jnp.asarray(rng.normal(size=(num_pages, page_size, KVH, D)), dtype)
+    kp = jnp.asarray(rng.normal(size=(num_pages, KVH, page_size, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(num_pages, KVH, page_size, D)), dtype)
     pt = jnp.asarray(scatter_pages(rng, lens, num_pages, page_size))
     sl = jnp.asarray(lens, jnp.int32)
     out = paged_decode_attention(q, kp, vp, pt, sl, interpret=True)
@@ -221,8 +221,8 @@ def test_paged_attention_from_allocator():
     pt, sl = page_table_from_allocator(alloc, seq_ids)
     B, H = len(seq_ids), 4
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(64, page_size, KVH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(64, page_size, KVH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(64, KVH, page_size, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(64, KVH, page_size, D)), jnp.float32)
     out_k = paged_attention(q, kp, vp, pt, sl, use_kernel=True, interpret=True)
     out_r = paged_attention(q, kp, vp, pt, sl, use_kernel=False)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
@@ -234,8 +234,8 @@ def test_paged_attention_ignores_stale_pages():
     rng = np.random.default_rng(2)
     B, H, KVH, D, page_size = 1, 4, 2, 32, 8
     lens = [20]
-    kp = jnp.asarray(rng.normal(size=(32, page_size, KVH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(32, page_size, KVH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(32, KVH, page_size, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(32, KVH, page_size, D)), jnp.float32)
     pt = jnp.asarray(scatter_pages(rng, lens, 32, page_size))
     sl = jnp.asarray(lens, jnp.int32)
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
@@ -313,6 +313,42 @@ def test_expert_ffn_swiglu_matches_dense():
     dense = jnp.where(valid, dense, 0.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                rtol=2e-3, atol=2e-3)
+
+
+def _call_gmm(rng):
+    from repro.kernels.grouped_matmul.ops import gmm
+
+    x = jnp.asarray(rng.normal(size=(2, 128, 128)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(2, 128, 128)), jnp.float32)
+    return gmm(x, w, jnp.asarray([128, 64], jnp.int32), use_kernel=True)
+
+
+def _call_paged(rng):
+    from repro.kernels.paged_attention.ops import paged_attention
+
+    q = jnp.asarray(rng.normal(size=(1, 4, 32)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(8, 2, 8, 32)), jnp.float32)
+    pt = jnp.asarray([[0, 1]], jnp.int32)
+    return paged_attention(q, kp, kp, pt, jnp.asarray([12], jnp.int32),
+                           use_kernel=True)
+
+
+def _call_packed(rng):
+    q, k, v = make_qkv(rng, 1, 128, 2, 2, 32, jnp.float32)
+    seg = jnp.ones((1, 128), jnp.int32)
+    return packed_attention(q, k, v, seg, seg, use_kernel=True)
+
+
+@pytest.mark.parametrize("call", [_call_gmm, _call_paged, _call_packed],
+                         ids=["grouped_matmul", "paged", "packed"])
+def test_kernel_off_tpu_without_interpret_raises(call):
+    """The one dispatch rule: off the TPU a kernel runs only when
+    ``interpret=True`` is passed; it never falls back on its own."""
+    from repro.kernels.dispatch import KernelBackendError
+
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(KernelBackendError, match="needs a TPU"):
+        call(np.random.default_rng(0))
 
 
 def test_kernel_matches_model_flash_attention():

@@ -105,6 +105,7 @@ def test_distributed_decode_matches_single_device():
         from repro.distributed.context import activation_sharding
         from repro.distributed.sharding import (
             batch_shardings, cache_shardings, make_rules, param_shardings)
+        from repro.launch.mesh import make_mesh
         from repro.models import build_model, init_params
 
         cfg = get_config("qwen2-72b").smoke()   # GQA kv < model-axis size
@@ -123,7 +124,7 @@ def test_distributed_decode_matches_single_device():
                 params, {"tokens": prompt[:, t:t+1]}, cache)
 
         # distributed: (data=2, model=4) mesh, sequence-sharded cache
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = make_rules(mesh, "serve")
         p_shard = param_shardings(model.param_specs(), mesh, rules)
         params_d = jax.device_put(params, p_shard)
@@ -162,6 +163,7 @@ def test_moe_group_local_dispatch_matches_single_device():
         from repro.distributed.context import activation_sharding
         from repro.distributed.sharding import (
             batch_shardings, make_rules, param_shardings)
+        from repro.launch.mesh import make_mesh
         from repro.models import build_model, init_params, make_batch
 
         cfg = get_config("qwen3-moe-30b-a3b").smoke()
@@ -171,7 +173,7 @@ def test_moe_group_local_dispatch_matches_single_device():
 
         loss_ref, _ = model.loss(params, batch)   # G = 1
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rules = make_rules(mesh, "fsdp")
         p_shard = param_shardings(model.param_specs(), mesh, rules)
         params_d = jax.device_put(params, p_shard)

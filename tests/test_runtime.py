@@ -535,13 +535,62 @@ def test_live_jax_payload_runs_real_kernels():
     cfg.t_max = scn.smoke_t_max
     res = run_live(
         scn.make_stream(0, n_images=8, duration_range=(4.0, 8.0)), cfg,
-        runtime=RuntimeConfig(time_scale=0.01, payload="jax"),
+        runtime=RuntimeConfig(time_scale=0.01, payload="jax",
+                              payload_kwargs={"interpret": True}),
     )
     assert res.completed == res.total == 8
     # service time = kernel wall time + calibrated padding >= the message's
     # scenario duration (small tolerance: clock/perf_counter jitter)
     for m in res.messages:
         assert m.done_t - m.start_t >= m.duration - 0.5
+
+
+def test_jax_payload_refuses_cpu_without_interpret():
+    """Off the TPU the payload's kernel runs only when interpret mode is
+    asked for; it never falls back to the interpreter on its own."""
+    from repro.kernels.dispatch import KernelBackendError
+
+    with pytest.raises(KernelBackendError, match="needs a TPU"):
+        make_payload("jax")
+    assert make_payload("jax", interpret=True).check_error < 1e-4
+
+
+def test_multiproc_refuses_device_payload(monkeypatch):
+    """A device payload is refused by the multiproc transport before the
+    payload or any worker process is built."""
+    import multiprocessing as mp
+
+    from repro.runtime.payloads import JaxPayload
+
+    def built(*a, **k):
+        raise AssertionError("the device payload was constructed")
+
+    monkeypatch.setattr(JaxPayload, "__init__", built)
+    scn = get_scenario("microscopy")
+    with pytest.raises(ValueError, match="only one process may hold"):
+        run_scenario(
+            "microscopy", backend="multiproc", n_runs=1,
+            runtime=RuntimeConfig(time_scale=0.01, payload="jax",
+                                  payload_kwargs={"interpret": True}),
+            stream_overrides=scn.smoke_overrides, t_max=scn.smoke_t_max,
+        )
+    assert mp.active_children() == []
+
+
+def test_parallel_sweep_refuses_device_payload(monkeypatch):
+    from repro.runtime.payloads import JaxPayload
+    from repro.scenarios import sweep_policies
+
+    def built(*a, **k):
+        raise AssertionError("the device payload was constructed")
+
+    monkeypatch.setattr(JaxPayload, "__init__", built)
+    with pytest.raises(ValueError, match="accelerator belongs to one process"):
+        sweep_policies(
+            "microscopy", ["first-fit", "best-fit"], jobs=2, backend="live",
+            runtime=RuntimeConfig(payload="jax",
+                                  payload_kwargs={"interpret": True}),
+        )
 
 
 def test_run_scenario_rejects_unknown_backend():

@@ -13,21 +13,12 @@ from repro.launch.hlo_analysis import (
 )
 
 
-def abstract_mesh(shape, names):
-    """AbstractMesh across jax versions: 0.4.x takes (name, size) pairs,
-    newer jax takes positional (shape, names)."""
-    try:
-        return AbstractMesh(tuple(zip(names, shape, strict=True)))
-    except TypeError:
-        return AbstractMesh(shape, names)
-
-
 def mesh_16x16():
-    return abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def mesh_2x16x16():
-    return abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 # ---------------------------------------------------------------------------
@@ -197,3 +188,12 @@ def test_top_collectives_ranking():
     hlo = jax.jit(f).lower(jnp.zeros((32, 32))).compile().as_text()
     rows = top_collectives(hlo, n=5)
     assert isinstance(rows, list)  # no collectives on 1 device -> empty ok
+
+
+def test_peaks_keyed_by_device_kind():
+    from repro.launch.analysis import peaks
+
+    assert peaks("TPU v5 lite")["peak_flops_bf16"] == 197e12
+    assert peaks("TPU v5 lite")["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")
