@@ -114,4 +114,5 @@ def grouped_matmul(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, C, f), x.dtype),
         interpret=interpret,
+        name="grouped_matmul",
     )(group_sizes.astype(jnp.int32), x, w)

@@ -173,4 +173,5 @@ def packed_flash_attention(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="packed_attention",
     )(segment_ids_q[:, :, None], segment_ids_kv[:, None, :], q, k, v)

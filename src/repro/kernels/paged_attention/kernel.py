@@ -157,5 +157,6 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(table, seq_lens.astype(jnp.int32), q_g, k_pool, v_pool)
     return out.reshape(B, H, D)

@@ -287,7 +287,8 @@ class DecoderLM:
         ``init_cache(B, max_len)`` lays them out: decode writes past the
         end of an array would be dropped."""
         cfg = self.cfg
-        x = self._embed(params, batch)
+        with jax.named_scope("embed"):
+            x = self._embed(params, batch)
         seg = batch["segment_ids"]
         pos_ids = batch["positions"]
         B, S = seg.shape
@@ -300,11 +301,13 @@ class DecoderLM:
             for pos, char in enumerate(cfg.pattern):
                 p = period_params[str(pos)]
                 x = constrain(x, ("batch", "seq", None))
-                h = norm(p["ln1"], cfg.norm_type, x)
+                with jax.named_scope("norm"):
+                    h = norm(p["ln1"], cfg.norm_type, x)
                 if char == "A":
-                    out, (k, v) = attention(p["mixer"], cfg, h, seg, pos_ids)
-                    caches[str(pos)] = {"k": jnp.pad(k, room),
-                                        "v": jnp.pad(v, room)}
+                    with jax.named_scope("attention"):
+                        out, (k, v) = attention(p["mixer"], cfg, h, seg, pos_ids)
+                        caches[str(pos)] = {"k": jnp.pad(k, room),
+                                            "v": jnp.pad(v, room)}
                 elif char == "M":
                     out, st = ssm_lib.mamba_forward(p["mixer"], cfg, h)
                     caches[str(pos)] = st
@@ -316,22 +319,27 @@ class DecoderLM:
                     caches[str(pos)] = st
                 x = x + out
                 if "ffn" in p:
-                    h = norm(p["ln2"], cfg.norm_type, x)
-                    if "router" in p["ffn"]:
-                        out, _ = moe_lib.moe_layer(p["ffn"], cfg, h)
-                    else:
-                        out = mlp(p["ffn"], cfg, h)
+                    with jax.named_scope("norm"):
+                        h = norm(p["ln2"], cfg.norm_type, x)
+                    with jax.named_scope("mlp"):
+                        if "router" in p["ffn"]:
+                            out, _ = moe_lib.moe_layer(p["ffn"], cfg, h)
+                        else:
+                            out = mlp(p["ffn"], cfg, h)
                     x = x + out
             return x, caches
 
-        x, caches = lax.scan(period_body, x, params["blocks"])
-        x = norm(params["final_norm"], cfg.norm_type, x)
-        # last valid position per row
-        last = jnp.maximum(jnp.sum((seg > 0).astype(jnp.int32), axis=1) - 1, 0)
-        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-        logits = x_last.astype(jnp.float32) @ self._table(params).T.astype(
-            jnp.float32
-        )
+        with jax.named_scope("layers"):
+            x, caches = lax.scan(period_body, x, params["blocks"])
+        with jax.named_scope("norm"):
+            x = norm(params["final_norm"], cfg.norm_type, x)
+        with jax.named_scope("logits"):
+            # last valid position per row
+            last = jnp.maximum(jnp.sum((seg > 0).astype(jnp.int32), axis=1) - 1, 0)
+            x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+            logits = x_last.astype(jnp.float32) @ self._table(params).T.astype(
+                jnp.float32
+            )
         cache = {
             "blocks": caches,
             "len": jnp.sum((seg > 0).astype(jnp.int32), axis=1),
@@ -347,7 +355,8 @@ class DecoderLM:
     ) -> Tuple[jax.Array, Dict[str, Any]]:
         """One token for every sequence in the batch.  Cache is donated."""
         cfg = self.cfg
-        x = jnp.take(params["embed"], batch["tokens"], axis=0)  # (B, 1, d)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], batch["tokens"], axis=0)  # (B, 1, d)
         new_len = cache["len"] + 1  # includes the new token
         position = cache["len"]     # 0-based position of the new token
 
@@ -358,12 +367,14 @@ class DecoderLM:
                 p = period_params[str(pos)]
                 c = period_cache[str(pos)]
                 x = constrain(x, ("batch", None, None))
-                h = norm(p["ln1"], cfg.norm_type, x)
+                with jax.named_scope("norm"):
+                    h = norm(p["ln1"], cfg.norm_type, x)
                 if char == "A":
-                    out, kv = attention_decode(
-                        p["mixer"], cfg, h, position,
-                        KVCache(k=c["k"], v=c["v"]), new_len,
-                    )
+                    with jax.named_scope("attention"):
+                        out, kv = attention_decode(
+                            p["mixer"], cfg, h, position,
+                            KVCache(k=c["k"], v=c["v"]), new_len,
+                        )
                     new_caches[str(pos)] = {"k": kv.k, "v": kv.v}
                 elif char == "M":
                     out, st = ssm_lib.mamba_decode_step(p["mixer"], cfg, h, c)
@@ -376,19 +387,24 @@ class DecoderLM:
                     new_caches[str(pos)] = st
                 x = x + out
                 if "ffn" in p:
-                    h = norm(p["ln2"], cfg.norm_type, x)
-                    if "router" in p["ffn"]:
-                        out, _ = moe_lib.moe_layer(p["ffn"], cfg, h)
-                    else:
-                        out = mlp(p["ffn"], cfg, h)
+                    with jax.named_scope("norm"):
+                        h = norm(p["ln2"], cfg.norm_type, x)
+                    with jax.named_scope("mlp"):
+                        if "router" in p["ffn"]:
+                            out, _ = moe_lib.moe_layer(p["ffn"], cfg, h)
+                        else:
+                            out = mlp(p["ffn"], cfg, h)
                     x = x + out
             return x, new_caches
 
-        x, new_blocks = lax.scan(period_body, x, (params["blocks"], cache["blocks"]))
-        x = norm(params["final_norm"], cfg.norm_type, x)
-        logits = x[:, 0].astype(jnp.float32) @ self._table(params).T.astype(
-            jnp.float32
-        )
+        with jax.named_scope("layers"):
+            x, new_blocks = lax.scan(period_body, x, (params["blocks"], cache["blocks"]))
+        with jax.named_scope("norm"):
+            x = norm(params["final_norm"], cfg.norm_type, x)
+        with jax.named_scope("logits"):
+            logits = x[:, 0].astype(jnp.float32) @ self._table(params).T.astype(
+                jnp.float32
+            )
         return logits, {"blocks": new_blocks, "len": new_len}
 
     # ---- cache allocation ----------------------------------------------------------

@@ -41,6 +41,7 @@ from ..core.resources import Resources
 from ..core.sim import SimConfig, SimResult, WorkerState
 from ..core.workloads import Stream
 from ..obs.audit import emit_packing_audit
+from ..obs.spans import span
 from .clock import ScaledClock
 from .lifecycle import Lifecycle
 from .master import Master
@@ -213,37 +214,40 @@ async def _drive(
     stats: Optional[Dict[str, object]],
     bus=None,
 ) -> SimResult:
-    if rt.measurement not in ("emulated", "os"):
-        raise ValueError(
-            f"measurement must be 'emulated' or 'os', got {rt.measurement!r}"
-        )
-    tkwargs = dict(rt.transport_kwargs)
-    if rt.transport == "multiproc":
-        tkwargs.setdefault("measurement", rt.measurement)
-    elif rt.measurement != "emulated":
-        raise ValueError(
-            "measurement='os' requires transport='multiproc' (the in-process"
-            " backend has no OS boundary to measure)"
-        )
-    transport = make_transport(rt.transport, **tkwargs)
-    if hasattr(transport, "set_payload_spec"):
-        # process-backed workers build their own payload instance; the
-        # transport refuses a device payload before anything is built
-        transport.set_payload_spec(rt.payload, rt.payload_kwargs)
-    clock = ScaledClock(rt.time_scale)
-    total = stream.num_messages
-    master = Master(total_expected=total, bus=bus)
-    # construct the payload before starting the clock: JaxPayload warms the
-    # jit cache at init, and that wall time must not burn virtual time
-    payload = make_payload(rt.payload, **rt.payload_kwargs)
-    poll = rt.poll_interval if rt.poll_interval is not None else cfg.dt
-    pool = WorkerPool(cfg, master, clock, payload, poll_interval=poll,
-                      transport=transport)
-    lifecycle = Lifecycle(pool, cfg, clock)
-    cluster = LiveCluster(cfg, irm, master, pool, lifecycle)
-    recorder = TraceRecorder(cfg)
-    rng = np.random.default_rng(cfg.seed)
-    dims = tuple(cfg.resource_dims)
+    with span("repro.live.setup"):
+        if rt.measurement not in ("emulated", "os"):
+            raise ValueError(
+                f"measurement must be 'emulated' or 'os', got "
+                f"{rt.measurement!r}"
+            )
+        tkwargs = dict(rt.transport_kwargs)
+        if rt.transport == "multiproc":
+            tkwargs.setdefault("measurement", rt.measurement)
+        elif rt.measurement != "emulated":
+            raise ValueError(
+                "measurement='os' requires transport='multiproc' (the"
+                " in-process backend has no OS boundary to measure)"
+            )
+        transport = make_transport(rt.transport, **tkwargs)
+        if hasattr(transport, "set_payload_spec"):
+            # process-backed workers build their own payload instance; the
+            # transport refuses a device payload before anything is built
+            transport.set_payload_spec(rt.payload, rt.payload_kwargs)
+        clock = ScaledClock(rt.time_scale)
+        total = stream.num_messages
+        master = Master(total_expected=total, bus=bus)
+        # construct the payload before starting the clock: JaxPayload warms
+        # the jit cache at init, and that wall time must not burn virtual
+        # time
+        payload = make_payload(rt.payload, **rt.payload_kwargs)
+        poll = rt.poll_interval if rt.poll_interval is not None else cfg.dt
+        pool = WorkerPool(cfg, master, clock, payload, poll_interval=poll,
+                          transport=transport)
+        lifecycle = Lifecycle(pool, cfg, clock)
+        cluster = LiveCluster(cfg, irm, master, pool, lifecycle)
+        recorder = TraceRecorder(cfg)
+        rng = np.random.default_rng(cfg.seed)
+        dims = tuple(cfg.resource_dims)
 
     clock.start()
     if bus is not None:
@@ -296,9 +300,10 @@ async def _drive(
                                 }
                             irm.ingest_report(report)
                 last_report_t = t
-            w0 = time.perf_counter()
-            step_metrics = irm.step(t, cluster)
-            step_wall_ms.append((time.perf_counter() - w0) * 1e3)
+            with span("repro.irm.step"):
+                w0 = time.perf_counter()
+                step_metrics = irm.step(t, cluster)
+                step_wall_ms.append((time.perf_counter() - w0) * 1e3)
             if bus is not None:
                 emit_packing_audit(bus, irm.config.allocator.algorithm,
                                    step_metrics.packing)
@@ -331,29 +336,31 @@ async def _drive(
                 stall_since = None
             t = round(t + cfg.dt, 9)
     finally:
-        feeder.cancel()
-        await asyncio.gather(feeder, return_exceptions=True)
-        await pool.shutdown()
+        with span("repro.live.shutdown"):
+            feeder.cancel()
+            await asyncio.gather(feeder, return_exceptions=True)
+            await pool.shutdown()
 
-    if stats is not None:
-        wall_s = time.perf_counter() - wall0
-        arr = np.asarray(step_wall_ms) if step_wall_ms else np.zeros(1)
-        stats.update(
-            wall_s=wall_s,
-            ticks=len(step_wall_ms),
-            irm_step_ms_mean=float(arr.mean()),
-            irm_step_ms_p50=float(np.percentile(arr, 50)),
-            irm_step_ms_p99=float(np.percentile(arr, 99)),
-            messages_per_s=len(master.completed) / max(wall_s, 1e-9),
-            transport=transport.stats(),
+    with span("repro.live.shutdown"):
+        if stats is not None:
+            wall_s = time.perf_counter() - wall0
+            arr = np.asarray(step_wall_ms) if step_wall_ms else np.zeros(1)
+            stats.update(
+                wall_s=wall_s,
+                ticks=len(step_wall_ms),
+                irm_step_ms_mean=float(arr.mean()),
+                irm_step_ms_p50=float(np.percentile(arr, 50)),
+                irm_step_ms_p99=float(np.percentile(arr, 99)),
+                messages_per_s=len(master.completed) / max(wall_s, 1e-9),
+                transport=transport.stats(),
+            )
+        return recorder.finalize(
+            completed=len(master.completed),
+            total=total,
+            makespan=master.max_done_t,
+            messages=[m for _, b in stream.batches for m in b],
+            requeued=master.requeued,
         )
-    return recorder.finalize(
-        completed=len(master.completed),
-        total=total,
-        makespan=master.max_done_t,
-        messages=[m for _, b in stream.batches for m in b],
-        requeued=master.requeued,
-    )
 
 
 def run_live(
@@ -380,4 +387,5 @@ def run_live(
     else:
         irm.begin_run()
     rt = runtime or RuntimeConfig()
-    return asyncio.run(_drive(stream, cfg, irm, rt, stats, bus=bus))
+    with span("repro.live.run"):
+        return asyncio.run(_drive(stream, cfg, irm, rt, stats, bus=bus))

@@ -33,6 +33,7 @@ import asyncio
 import time
 from typing import Callable, Dict
 
+from ..obs.spans import span
 from .annotations import worker_side
 
 __all__ = ["SleepPayload", "JaxPayload", "make_payload", "PAYLOADS"]
@@ -122,12 +123,21 @@ class JaxPayload:
             )
         return err
 
+    @worker_side
+    def _kernel(self, msg_id: int):
+        # ``_compute`` is looked up here, per call, so that a wrapper put
+        # in its place after construction is the one timed
+        with span("repro.payload.kernel", msg_id=msg_id):
+            return self._compute()
+
     async def __call__(self, msg, clock) -> None:
         loop = asyncio.get_running_loop()
         wall0 = time.perf_counter()
-        await loop.run_in_executor(None, self._compute)
+        with span("repro.payload.call", msg_id=msg.msg_id):
+            await loop.run_in_executor(None, self._kernel, msg.msg_id)
         spent_virtual = (time.perf_counter() - wall0) / clock.time_scale
-        await clock.sleep(msg.duration - spent_virtual)
+        with span("repro.payload.pad"):
+            await clock.sleep(msg.duration - spent_virtual)
 
     @worker_side
     def run_sync(self, msg, time_scale: float) -> None:

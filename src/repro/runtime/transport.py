@@ -64,6 +64,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ..core.sim import PEState, WorkerState
 from ..core.workloads import Message
+from ..obs.spans import span
 from .annotations import loop_only, transition, worker_side
 
 __all__ = [
@@ -181,7 +182,8 @@ class InProcTransport(Transport):
         master = pool.master
         bus = master.bus
         try:
-            await clock.sleep(cfg.pe_start_delay)
+            with span("repro.pe.start"):
+                await clock.sleep(cfg.pe_start_delay)
             pe.state = PEState.IDLE
             pe.idle_since = clock.now()
             while True:

@@ -41,6 +41,7 @@ from ..core.profiler import WorkerProbe
 from ..core.queues import HostRequest
 from ..core.sim import PEState, SimConfig, WorkerState
 from ..core.workloads import Message
+from ..obs.spans import close_span, open_span
 from .annotations import loop_only, transition
 from .clock import ScaledClock
 from .master import Master
@@ -132,6 +133,8 @@ class WorkerPool:
         #   _off_heap    min-heap of OFF slot indices; stale entries (slot
         #                rebooted meanwhile) are discarded lazily on peek
         self._booting: Dict[int, float] = {}
+        # idx -> the open ``repro.worker.boot`` span of each BOOTING worker
+        self._boot_spans: Dict[int, object] = {}
         self._active_idx: List[int] = []
         self._off_heap: List[int] = []
         self._n_alive = 0
@@ -148,6 +151,7 @@ class WorkerPool:
         bus = self.master.bus
         for idx in due:
             del self._booting[idx]
+            self._end_boot_span(idx)
             self.workers[idx].state = WorkerState.ACTIVE
             insort(self._active_idx, idx)
             if bus is not None:
@@ -179,6 +183,8 @@ class WorkerPool:
         self._n_alive += 1
         if w.state is WorkerState.BOOTING:
             self._booting[w.idx] = w.ready_t
+            self._boot_spans[w.idx] = open_span("repro.worker.boot",
+                                                worker=w.idx)
         else:  # zero boot delay: born ACTIVE
             insort(self._active_idx, w.idx)
         if self.master.bus is not None:
@@ -214,6 +220,7 @@ class WorkerPool:
         w.state = WorkerState.BOOTING
         w.ready_t = ready_t
         self._booting[w.idx] = ready_t
+        self._boot_spans[w.idx] = open_span("repro.worker.boot", worker=w.idx)
         self._n_alive += 1
         if self.master.bus is not None:
             self.master.bus.emit("worker.boot", worker=w.idx,
@@ -261,10 +268,17 @@ class WorkerPool:
                 self._active_idx.remove(idx)
             else:  # BOOTING victim
                 self._booting.pop(idx, None)
+                self._end_boot_span(idx)
             self._n_alive -= 1
             heapq.heappush(self._off_heap, idx)
         w.state = WorkerState.OFF
         return harvested
+
+    @loop_only
+    def _end_boot_span(self, idx: int) -> None:
+        s = self._boot_spans.pop(idx, None)
+        if s is not None:
+            close_span(s)
 
     # ---- placement actuation ----------------------------------------------
     @loop_only
@@ -296,4 +310,6 @@ class WorkerPool:
     # ---- shutdown ----------------------------------------------------------
     async def shutdown(self) -> None:
         """Tear down every PE/worker the transport still hosts."""
+        for idx in list(self._boot_spans):
+            self._end_boot_span(idx)
         await self.transport.close()
