@@ -219,29 +219,35 @@ class EncDecLM:
             (cache["enc_segment_ids"] > 0).astype(jnp.int32), axis=1
         )
 
-        def body(x, xs):
-            p, c = xs
+        blocks = cache["blocks"]
+
+        def body(carry, xs):
+            x, kv = carry
+            p, cross, i = xs  # cross K/V are read, never written
             x = constrain(x, ("batch", None, None))
             h = norm(p["ln1"], cfg.norm_type, x)
             out, kv = attention_decode(
-                p["self_attn"], cfg, h, position,
-                KVCache(k=c["k"], v=c["v"]), new_len,
+                p["self_attn"], cfg, h, position, kv, i, new_len,
             )
             x = x + out
             h = norm(p["ln_cross"], cfg.norm_type, x)
             q, _, _ = _project_qkv(p["cross_attn"], cfg, h, h)
-            out = decode_attention(q, c["ck"], c["cv"], enc_valid)
+            out = decode_attention(q, cross["ck"], cross["cv"], enc_valid)
             out = jnp.einsum("bshk,hkd->bsd", out, p["cross_attn"]["wo"])
             x = x + out
             h = norm(p["ln2"], cfg.norm_type, x)
             x = x + mlp(p["ffn"], cfg, h)
-            return x, {"k": kv.k, "v": kv.v, "ck": c["ck"], "cv": c["cv"]}
+            return (x, kv), None
 
-        x, new_blocks = lax.scan(body, x, (params["dec_blocks"], cache["blocks"]))
+        (x, kv), _ = lax.scan(
+            body, (x, KVCache(k=blocks["k"], v=blocks["v"])),
+            (params["dec_blocks"], {"ck": blocks["ck"], "cv": blocks["cv"]},
+             jnp.arange(cfg.n_layers)),
+        )
         x = norm(params["final_norm"], cfg.norm_type, x)
         logits = x[:, 0].astype(jnp.float32) @ params["lm_head"].T.astype(jnp.float32)
         return logits, {
-            "blocks": new_blocks,
+            "blocks": dict(blocks, k=kv.k, v=kv.v),
             "enc_segment_ids": cache["enc_segment_ids"],
             "len": new_len,
         }

@@ -465,12 +465,13 @@ def attention_specs(cfg: Any, cross: bool = False) -> Dict[str, Any]:
     return specs
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class KVCache:
-    """Dense per-layer KV cache carried through decode steps."""
+    """Every layer's dense KV cache, stacked, carried through decode steps."""
 
-    k: jax.Array  # (B, S_max, KVH, D)
-    v: jax.Array  # (B, S_max, KVH, D)
+    k: jax.Array  # (n_layers, B, S_max, KVH, D)
+    v: jax.Array  # (n_layers, B, S_max, KVH, D)
 
 
 def _project_qkv(
@@ -530,22 +531,30 @@ def attention_decode(
     x: jax.Array,              # (B, 1, d)
     position: jax.Array,       # (B,) within-sequence position of the token
     cache: KVCache,
+    layer: jax.Array,          # () index of this layer in the stacked cache
     cache_len: jax.Array,      # (B,) valid entries *including* this token
     *,
     use_rope: bool = True,
 ) -> Tuple[jax.Array, KVCache]:
-    """One decode step: append to cache, attend over it."""
+    """One decode step of one layer: write the token's K/V into row
+    ``cache_len - 1`` of layer ``layer`` of the stacked cache, attend over
+    that layer.
+
+    Carried through a ``lax.scan`` over layers, the stack is written in
+    place, a few rows a step.  Fed to the scan as ``xs`` and returned as
+    ``ys``, each layer's slab would be copied out, and the whole stack
+    back, every step."""
     B = x.shape[0]
     q, k, v = _project_qkv(p, cfg, x, x)
     if use_rope:
         pos = position.reshape(B, 1)
         q = rope(q, pos, cfg.rope_theta)
         k = rope(k, pos, cfg.rope_theta)
-    # scatter the new token into the cache at cache_len - 1
     write_idx = (cache_len - 1).astype(jnp.int32)  # (B,)
     b_idx = jnp.arange(B, dtype=jnp.int32)
-    k_cache = cache.k.at[b_idx, write_idx].set(k[:, 0].astype(cache.k.dtype))
-    v_cache = cache.v.at[b_idx, write_idx].set(v[:, 0].astype(cache.v.dtype))
+    k_all = cache.k.at[layer, b_idx, write_idx].set(k[:, 0].astype(cache.k.dtype))
+    v_all = cache.v.at[layer, b_idx, write_idx].set(v[:, 0].astype(cache.v.dtype))
+    k_cache, v_cache = k_all[layer], v_all[layer]
     # distributed flash-decode when the cache is sequence-sharded under the
     # active mesh; dense path otherwise (single device, tests)
     out = decode_attention_distributed(
@@ -556,7 +565,7 @@ def attention_decode(
             q, k_cache, v_cache, cache_len, window=cfg.sliding_window
         )
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-    return out, KVCache(k=k_cache, v=v_cache)
+    return out, KVCache(k=k_all, v=v_all)
 
 
 # ---------------------------------------------------------------------------

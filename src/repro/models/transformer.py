@@ -359,32 +359,36 @@ class DecoderLM:
             x = jnp.take(params["embed"], batch["tokens"], axis=0)  # (B, 1, d)
         new_len = cache["len"] + 1  # includes the new token
         position = cache["len"]     # 0-based position of the new token
+        # attention caches ride in the carry, written in place; the
+        # recurrent blocks' small states go through the scan as xs / ys
+        blocks = cache["blocks"]
+        kv = {str(pos): blocks[str(pos)]
+              for pos, char in enumerate(cfg.pattern) if char == "A"}
+        states = {pos: c for pos, c in blocks.items() if pos not in kv}
+        recurrent = {"M": ssm_lib.mamba_decode_step,
+                     "l": xlstm_lib.mlstm_decode_step,
+                     "s": xlstm_lib.slstm_decode_step}
 
-        def period_body(x, xs):
-            period_params, period_cache = xs
-            new_caches = {}
+        def period_body(carry, xs):
+            x, kv = carry
+            period_params, i, period_states = xs
+            new_states = {}
             for pos, char in enumerate(cfg.pattern):
-                p = period_params[str(pos)]
-                c = period_cache[str(pos)]
+                key = str(pos)
+                p = period_params[key]
                 x = constrain(x, ("batch", None, None))
                 with jax.named_scope("norm"):
                     h = norm(p["ln1"], cfg.norm_type, x)
                 if char == "A":
                     with jax.named_scope("attention"):
-                        out, kv = attention_decode(
-                            p["mixer"], cfg, h, position,
-                            KVCache(k=c["k"], v=c["v"]), new_len,
+                        out, c = attention_decode(
+                            p["mixer"], cfg, h, position, KVCache(**kv[key]),
+                            i, new_len,
                         )
-                    new_caches[str(pos)] = {"k": kv.k, "v": kv.v}
-                elif char == "M":
-                    out, st = ssm_lib.mamba_decode_step(p["mixer"], cfg, h, c)
-                    new_caches[str(pos)] = st
-                elif char == "l":
-                    out, st = xlstm_lib.mlstm_decode_step(p["mixer"], cfg, h, c)
-                    new_caches[str(pos)] = st
+                    kv[key] = {"k": c.k, "v": c.v}
                 else:
-                    out, st = xlstm_lib.slstm_decode_step(p["mixer"], cfg, h, c)
-                    new_caches[str(pos)] = st
+                    out, new_states[key] = recurrent[char](
+                        p["mixer"], cfg, h, period_states[key])
                 x = x + out
                 if "ffn" in p:
                     with jax.named_scope("norm"):
@@ -395,17 +399,20 @@ class DecoderLM:
                         else:
                             out = mlp(p["ffn"], cfg, h)
                     x = x + out
-            return x, new_caches
+            return (x, kv), new_states
 
         with jax.named_scope("layers"):
-            x, new_blocks = lax.scan(period_body, x, (params["blocks"], cache["blocks"]))
+            (x, kv), states = lax.scan(
+                period_body, (x, kv),
+                (params["blocks"], jnp.arange(cfg.n_periods), states),
+            )
         with jax.named_scope("norm"):
             x = norm(params["final_norm"], cfg.norm_type, x)
         with jax.named_scope("logits"):
             logits = x[:, 0].astype(jnp.float32) @ self._table(params).T.astype(
                 jnp.float32
             )
-        return logits, {"blocks": new_blocks, "len": new_len}
+        return logits, {"blocks": {**states, **kv}, "len": new_len}
 
     # ---- cache allocation ----------------------------------------------------------
     def init_cache(

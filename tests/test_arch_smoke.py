@@ -303,3 +303,97 @@ def test_run_local_decode_matches_teacher_forced():
     assert out["decode_logits"].shape == (fed.shape[0], gen, ref[0].shape[-1])
     np.testing.assert_allclose(out["decode_logits"], np.stack(ref[T:], axis=1),
                                rtol=1e-4, atol=1e-4)
+
+
+def _per_layer_decode_step(model, params, tok, cache):
+    """A plain decode step to hold the scanned one to: a Python loop over
+    layers, each attention layer's cache sliced out of the stack, decoded
+    by ``attention_decode`` as a one-layer stack, and stacked back."""
+    from repro.models import moe as moe_lib, ssm as ssm_lib
+    from repro.models import xlstm as xlstm_lib
+    from repro.models.layers import KVCache, attention_decode, mlp, norm
+
+    cfg = model.cfg
+    step = {"M": ssm_lib.mamba_decode_step, "l": xlstm_lib.mlstm_decode_step,
+            "s": xlstm_lib.slstm_decode_step}
+    x = jnp.take(params["embed"], tok, axis=0)
+    length = cache["len"] + 1
+    layers = {key: [] for key in cache["blocks"]}
+    for i in range(cfg.n_periods):
+        for pos, char in enumerate(cfg.pattern):
+            key = str(pos)
+            p = jax.tree.map(lambda a: a[i], params["blocks"][key])
+            c = jax.tree.map(lambda a: a[i], cache["blocks"][key])
+            h = norm(p["ln1"], cfg.norm_type, x)
+            if char == "A":
+                out, kv = attention_decode(
+                    p["mixer"], cfg, h, cache["len"],
+                    KVCache(k=c["k"][None], v=c["v"][None]), 0, length)
+                c = {"k": kv.k[0], "v": kv.v[0]}
+            else:
+                out, c = step[char](p["mixer"], cfg, h, c)
+            layers[key].append(c)
+            x = x + out
+            if "ffn" in p:
+                h = norm(p["ln2"], cfg.norm_type, x)
+                if "router" in p["ffn"]:
+                    out, _ = moe_lib.moe_layer(p["ffn"], cfg, h)
+                else:
+                    out = mlp(p["ffn"], cfg, h)
+                x = x + out
+    x = norm(params["final_norm"], cfg.norm_type, x)
+    logits = x[:, 0].astype(jnp.float32) @ model._table(params).T.astype(
+        jnp.float32)
+    blocks = {key: jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+              for key, per_layer in layers.items()}
+    return logits, {"blocks": blocks, "len": length}
+
+
+@pytest.mark.parametrize("arch,window", [
+    ("olmo-1b", 0),         # dense MHA
+    ("qwen3-8b", 3),        # GQA, a sliding window shorter than the context
+    ("jamba-v0.1-52b", 0),  # Mamba blocks and MoE beside one attention block
+])
+def test_decode_step_matches_per_layer_reference(arch, window):
+    """The scanned decode step, its K/V stacks carried through the layer
+    scan and written in place, equals a per-layer loop over sliced caches:
+    the same logits and caches, each step writing exactly row ``len - 1``
+    of every layer's K and V and leaving the rows past ``len`` zero."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config(arch).smoke(), sliding_window=window)
+    model = build_model(cfg)
+    params = init_params(model.param_specs(), jax.random.PRNGKey(0))
+    rng = np.random.default_rng(5)
+    B, max_len, steps = 3, 12, 6
+    cache = model.init_cache(B, max_len, dtype=jnp.float32)
+    cache["len"] = jnp.asarray([0, 3, 5], jnp.int32)  # rows at different points
+    ref_cache = cache
+    step = jax.jit(model.decode_step)
+    ref_step = jax.jit(lambda p, t, c: _per_layer_decode_step(model, p, t, c))
+    attn = [str(pos) for pos, c in enumerate(cfg.pattern) if c == "A"]
+    rows = np.arange(max_len)
+    for _ in range(steps):
+        tok = jnp.asarray(rng.integers(1, cfg.vocab_size, size=(B, 1)),
+                          jnp.int32)
+        old = jax.tree.map(np.asarray, cache)
+        logits, cache = step(params, {"tokens": tok}, cache)
+        ref_logits, ref_cache = ref_step(params, tok, ref_cache)
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
+                                   rtol=0, atol=1e-6)
+        for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(ref_cache)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=0, atol=1e-6)
+        new_len = np.asarray(cache["len"])
+        np.testing.assert_array_equal(new_len, old["len"] + 1)
+        for key in attn:
+            for name in ("k", "v"):
+                new, before = np.asarray(cache["blocks"][key][name]), \
+                    old["blocks"][key][name]
+                for b in range(B):
+                    written = rows == new_len[b] - 1
+                    np.testing.assert_array_equal(new[:, b, ~written],
+                                                  before[:, b, ~written])
+                    assert np.all(np.any(new[:, b, written] != 0,
+                                         axis=(-2, -1)))
+                    assert not np.any(new[:, b, new_len[b]:])

@@ -11,6 +11,7 @@ Nothing runs, so these say nothing about results or times.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,20 +96,60 @@ def test_packed_attention_compiles(one_chip, dtype):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_olmo_1b_decode_step_compiles(one_chip):
-    """Full-width OLMo-1B decode (f32 weights, batch 8) fits one chip."""
+def _top_level_instructions(hlo: str):
+    """(computation, instruction line) for every instruction outside the
+    computations that fusions call."""
+    comps = re.split(r"\n(?=\S)", hlo)
+    fused = {name for line in hlo.splitlines() if " fusion(" in line
+             for name in re.findall(r"calls=%([\w.\-]+)", line)}
+    for comp in comps:
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+)", comp)
+        if head is None or head.group(1) in fused:
+            continue
+        for line in comp.splitlines()[1:]:
+            yield head.group(1), line.strip()
+
+
+@pytest.mark.parametrize(
+    "B,max_len",
+    [(8, 32),     # a short cache
+     (12, 1279),  # the decode cell: 1024-token prompts, 256 tokens
+     (8, 2048)],  # the prefill cell: OLMo-1B's whole context
+)
+def test_olmo_1b_decode_step_compiles(one_chip, B, max_len):
+    """Full-width OLMo-1B decode (f32 weights and cache) fits one chip,
+    and the step writes its K/V rows into the donated cache in place: no
+    layer's slab is copied out of the stack, and no stack is copied."""
     from repro.configs import get_config
     from repro.models import abstract_params, build_model
 
-    model = build_model(get_config("olmo-1b"))
+    cfg = get_config("olmo-1b")
+    model = build_model(cfg)
     put = lambda a: _sds(one_chip, a.shape, a.dtype)  # noqa: E731
     params = jax.tree.map(put, abstract_params(model.param_specs()))
     cache = jax.tree.map(put, jax.eval_shape(
-        lambda: model.init_cache(8, 32, dtype=jnp.float32)))
-    tokens = _sds(one_chip, (8, 1), jnp.int32)
+        lambda: model.init_cache(B, max_len, dtype=jnp.float32)))
+    tokens = _sds(one_chip, (B, 1), jnp.int32)
     c = jax.jit(model.decode_step, donate_argnums=(2,)).lower(
         params, {"tokens": tokens}, cache).compile()
     m = c.memory_analysis()
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert used < 16e9
+    # the parent of the in-place write held a copy of the stacks: 6.67e9
+    # bytes of temporaries at B=12
+    assert m.temp_size_in_bytes < 3.0e9
+    kv_bytes = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(cache["blocks"]))
+    assert m.alias_size_in_bytes >= kv_bytes
+
+    slab = (B, max_len, cfg.n_kv_heads, cfg.head_dim_)
+    shape = lambda dims: re.escape(  # noqa: E731
+        "f32[" + ",".join(map(str, dims)) + "]")
+    stack = shape((cfg.n_periods,) + slab) + r"\{[^}]*\} "
+    hlo = c.as_text()
+    # whole-stack copies, in or out of a fusion
+    assert not re.search(stack + r"(copy|dynamic-update-slice)\(", hlo)
+    for comp, line in _top_level_instructions(hlo):
+        assert not re.search(
+            r"= " + shape(slab) + r"\{[^}]*\} fusion\(", line), (comp, line)
