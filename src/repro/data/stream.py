@@ -26,6 +26,7 @@ import numpy as np
 
 from ..core.load_predictor import LoadPredictor, LoadPredictorConfig
 from ..core.profiler import MasterProfiler, ProfilerConfig
+from ..obs.spans import set_stats, span
 from .packing import PackedBatch, SequencePacker
 
 __all__ = ["StreamingPipeline"]
@@ -98,6 +99,16 @@ class StreamingPipeline:
 
     # ---- synchronous iteration ---------------------------------------------------
     def _next_batch(self) -> Optional[PackedBatch]:
+        """Pack the next batch: a ``repro.data.pack`` span whose stats are
+        its real (non-padding) tokens and its slots."""
+        with span("repro.data.pack") as s:
+            batch = self._pack_batch()
+            if batch is not None:
+                set_stats(s, real_tokens=batch.real_tokens,
+                          slots=batch.capacity)
+        return batch
+
+    def _pack_batch(self) -> Optional[PackedBatch]:
         while not self.packer.ready():
             if not self._ingest and not self.exhausted:
                 # each active shard ingests a chunk per tick (shard throughput)

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import argparse
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterable, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,7 @@ from ..distributed.context import activation_sharding
 from ..distributed.sharding import (batch_shardings, bytes_by_device,
                                     make_rules, param_shardings)
 from ..models import build_model, init_params
+from ..obs.spans import span
 from ..training import OptimizerConfig, init_opt_state, make_train_step
 from ..training.controller import TrainController, TrainControllerConfig
 from .compile_cache import enable_compilation_cache
@@ -56,6 +57,8 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
     ap.add_argument("--ckpt-every", type=int, default=100,
                     help="steps between checkpoints; 0 writes none")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="draws the initial parameters and the documents")
     return ap.parse_args(argv)
 
 
@@ -70,13 +73,21 @@ def run_geometry(args: argparse.Namespace):
     return cfg, seq_len, batch
 
 
-def host_batches(cfg, seq_len: int, batch: int) -> Iterator[Dict[str, Any]]:
-    """First-Fit-packed batches of synthetic documents, as host arrays."""
-    pipe = StreamingPipeline(
-        synthetic_documents(cfg.vocab_size, mean_len=seq_len // 3,
-                            max_len=4 * seq_len, seed=0),
-        seq_len=seq_len, batch_size=batch, prefetch=4,
-    )
+def host_batches(cfg, seq_len: int, batch: int, *, seed: int = 0,
+                 mean_len: Optional[float] = None, sigma: float = 0.9,
+                 max_len: Optional[int] = None,
+                 vocab: Optional[int] = None) -> Iterator[Dict[str, Any]]:
+    """First-Fit-packed batches of synthetic documents, as host arrays.
+
+    Document lengths are lognormal with mean ``mean_len`` (default a third
+    of a row) and shape ``sigma``, clipped to [8, ``max_len``] (default four
+    rows); token ids are Zipf over the first ``vocab`` ids (default the
+    model's vocabulary); ``seed`` draws both."""
+    docs = synthetic_documents(
+        vocab or cfg.vocab_size, mean_len=mean_len or seq_len // 3,
+        sigma=sigma, max_len=max_len or 4 * seq_len, seed=seed)
+    pipe = StreamingPipeline(docs, seq_len=seq_len, batch_size=batch,
+                             prefetch=4)
     for pb in pipe:
         yield {
             "tokens": pb.tokens,
@@ -84,6 +95,47 @@ def host_batches(cfg, seq_len: int, batch: int) -> Iterator[Dict[str, Any]]:
             "segment_ids": pb.segment_ids,
             "positions": pb.positions,
         }
+
+
+def device_batches(hosts: Iterable[Dict[str, Any]], mesh,
+                   rules) -> Iterator[Dict[str, Any]]:
+    """Host batches placed on the mesh by ``batch_shardings``; each wait
+    for the next one (packing, transfer) is a ``repro.train.batch`` span."""
+    hosts = iter(hosts)
+    shard = None
+    while True:
+        with span("repro.train.batch"):
+            host = next(hosts, None)
+            if host is None:
+                return
+            if shard is None:
+                shard = batch_shardings(
+                    {k: jax.ShapeDtypeStruct(v.shape, jnp.int32)
+                     for k, v in host.items()}, mesh, rules)
+            dev = {k: jax.device_put(v, shard[k]) for k, v in host.items()}
+        yield dev
+
+
+def init_sharded_params(model, mesh, rules, seed: int = 0):
+    """The model's initial parameters from ``PRNGKey(seed)``, made on the
+    mesh in their ``param_shardings``; the same seed gives the same
+    parameters."""
+    specs = model.param_specs()
+    return jax.jit(lambda k: init_params(specs, k),
+                   out_shardings=param_shardings(specs, mesh, rules)
+                   )(jax.random.PRNGKey(seed))
+
+
+def jit_train_step(model, opt_cfg: OptimizerConfig, *, remat: str = "nothing",
+                   microbatches: int = 1):
+    """``make_train_step`` jitted with the parameters and the optimizer
+    state donated: bf16 compute copy, float32 master weights and Adam
+    moments.  Trace it under the mesh's ``activation_sharding``."""
+    return jax.jit(
+        make_train_step(model, opt_cfg, remat_policy=remat,
+                        microbatches=microbatches),
+        donate_argnums=(0, 1),
+    )
 
 
 def train(args: argparse.Namespace) -> Dict[str, Any]:
@@ -98,40 +150,18 @@ def train(args: argparse.Namespace) -> Dict[str, Any]:
     )
     rules = make_rules(mesh)
     model = build_model(cfg)
-    specs = model.param_specs()
-    p_shard = param_shardings(specs, mesh, rules)
 
     print(f"arch={cfg.name} mesh={dict(mesh.shape)} seq={seq_len} "
           f"batch={batch}")
     with mesh, activation_sharding(mesh, rules):
-        params = jax.jit(
-            lambda k: init_params(specs, k), out_shardings=p_shard
-        )(jax.random.PRNGKey(0))
+        params = init_sharded_params(model, mesh, rules, args.seed)
         held = bytes_by_device(params)
         opt_state = init_opt_state(params)
-        step_fn = jax.jit(
-            make_train_step(
-                model,
-                OptimizerConfig(decay_steps=max(args.steps, 100)),
-                remat_policy=args.remat,
-                microbatches=args.microbatches,
-            ),
-            donate_argnums=(0, 1),
-        )
-        b_shard = None
-
-        def batches():
-            nonlocal b_shard
-            for host in host_batches(cfg, seq_len, batch):
-                if b_shard is None:
-                    b_shard = batch_shardings(
-                        {k: jax.ShapeDtypeStruct(v.shape, jnp.int32)
-                         for k, v in host.items()},
-                        mesh, rules,
-                    )
-                yield {
-                    k: jax.device_put(v, b_shard[k]) for k, v in host.items()
-                }
+        step_fn = jit_train_step(
+            model, OptimizerConfig(decay_steps=max(args.steps, 100)),
+            remat=args.remat, microbatches=args.microbatches)
+        batches = device_batches(host_batches(cfg, seq_len, batch,
+                                              seed=args.seed), mesh, rules)
 
         ctl = TrainController(step_fn, TrainControllerConfig(
             checkpoint_dir=args.ckpt_dir, checkpoint_every=args.ckpt_every,
@@ -152,7 +182,7 @@ def train(args: argparse.Namespace) -> Dict[str, Any]:
                       f"grad_norm {grad_norms[-1]:.3f}")
 
         params, opt_state, summary = ctl.run(
-            params, opt_state, batches(), num_steps=args.steps,
+            params, opt_state, batches, num_steps=args.steps,
             start_step=start, on_metrics=on_metrics,
         )
         dt = time.perf_counter() - t0

@@ -16,8 +16,9 @@ Conventions:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -156,7 +157,11 @@ def _flash_q_chunk(
     window: int,
     chunk_kv: int,
     scale: float,
-) -> jax.Array:
+    with_lse: bool = False,
+):
+    """One query chunk's output (B, cq, H, D); with ``with_lse`` also each
+    query's log-sum-exp of its scores (B, H, cq), which the backward
+    recomputes the chunk's probabilities from."""
     B, cq, H, D = q.shape
     S = k.shape[1]
     n_kv = S // chunk_kv
@@ -209,7 +214,122 @@ def _flash_q_chunk(
         ),
     )
     out = acc / jnp.maximum(l_f[..., None], 1e-30)
-    return jnp.moveaxis(out, -2, 1)  # (B, cq, H, D)
+    out = jnp.moveaxis(out, -2, 1)  # (B, cq, H, D)
+    if with_lse:
+        return out, m_f + jnp.log(jnp.maximum(l_f, 1e-30))
+    return out
+
+
+class _FlashOpts(NamedTuple):
+    causal: bool
+    window: int
+    chunk_q: int
+    chunk_kv: int
+    q_offset: int
+    scale: float
+
+
+def _flash_forward(qp, kp, vp, sq, skv, o: _FlashOpts, with_lse: bool):
+    """Padded (B, Sq_p, H, D) inputs; the output (and the log-sum-exp,
+    (n_q, B, H, cq)) a query chunk at a time."""
+    B, Sq_p, H, D = qp.shape
+    n_q = Sq_p // o.chunk_q
+    qp = qp.reshape(B, n_q, o.chunk_q, H, D)
+    sq_c = sq.reshape(B, n_q, o.chunk_q)
+    q_idx = (
+        jnp.arange(Sq_p, dtype=jnp.int32).reshape(n_q, o.chunk_q) + o.q_offset
+    )
+
+    def one_chunk(xs):
+        q_c, seg_c, idx_c = xs
+        return _flash_q_chunk(
+            q_c, kp, vp, idx_c, seg_c, skv,
+            causal=o.causal, window=o.window, chunk_kv=o.chunk_kv,
+            scale=o.scale, with_lse=with_lse,
+        )
+
+    out = lax.map(
+        one_chunk, (jnp.moveaxis(qp, 1, 0), jnp.moveaxis(sq_c, 1, 0), q_idx)
+    )  # (n_q, B, cq, H, D)
+    if with_lse:
+        out, lse = out
+        return jnp.moveaxis(out, 0, 1).reshape(B, Sq_p, H, D), lse
+    return jnp.moveaxis(out, 0, 1).reshape(B, Sq_p, H, D)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _flash(qp, kp, vp, sq, skv, o: _FlashOpts):
+    return _flash_forward(qp, kp, vp, sq, skv, o, with_lse=False)
+
+
+def _flash_fwd(qp, kp, vp, sq, skv, o):
+    out, lse = _flash_forward(qp, kp, vp, sq, skv, o, with_lse=True)
+    return out, (qp, kp, vp, sq, skv, out, lse)
+
+
+def _flash_bwd(o: _FlashOpts, res, g):
+    """FlashAttention-2's backward: for each (query, key) block pair the
+    probabilities come back from the scores and the saved log-sum-exp;
+    dV += P^T dO, dS = P * (dO V^T - rowsum(dO * O)), dQ += dS K,
+    dK += dS^T Q.  Holds one block's scores at a time and the float32
+    dK, dV accumulators."""
+    qp, kp, vp, sq, skv, out, lse = res
+    B, Sq_p, H, D = qp.shape
+    Skv = kp.shape[1]
+    cq, ck = o.chunk_q, o.chunk_kv
+    n_q, n_kv = Sq_p // cq, Skv // ck
+    g = g.astype(jnp.float32)
+    delta = jnp.einsum("bshd,bshd->bhs", g, out)
+    delta = jnp.moveaxis(delta.reshape(B, H, n_q, cq), 2, 0)  # (n_q, B, H, cq)
+
+    def chunks(x, c):  # (B, S, ...) -> (S / c, B, c, ...)
+        return jnp.moveaxis(x.reshape(B, x.shape[1] // c, c, *x.shape[2:]),
+                            1, 0)
+
+    q_idx = jnp.arange(Sq_p, dtype=jnp.int32).reshape(n_q, cq) + o.q_offset
+    kv_idx = jnp.arange(Skv, dtype=jnp.int32).reshape(n_kv, ck)
+
+    def q_step(carry, xs):
+        dk, dv = carry  # (n_kv, B, ck, H, D) float32
+        q_c, g_c, lse_c, delta_c, sq_c, qi = xs
+        g_v = g_c.astype(vp.dtype)
+
+        def kv_step(dq, ys):
+            k_c, v_c, dk_c, dv_c, sk_c, ki = ys
+            s = jnp.einsum("bqhd,bkhd->bhqk", q_c, k_c,
+                           preferred_element_type=jnp.float32) * o.scale
+            mask = _mask_chunk(qi, ki, sq_c, sk_c, o.causal, o.window)
+            p = jnp.where(mask[:, None], jnp.exp(s - lse_c[..., None]), 0.0)
+            dv_c = dv_c + jnp.einsum("bhqk,bqhd->bkhd", p.astype(vp.dtype),
+                                     g_v, preferred_element_type=jnp.float32)
+            dp = jnp.einsum("bqhd,bkhd->bhqk", g_v, v_c,
+                            preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_c[..., None]) * o.scale
+            dq = dq + jnp.einsum("bhqk,bkhd->bqhd", ds.astype(kp.dtype), k_c,
+                                 preferred_element_type=jnp.float32)
+            dk_c = dk_c + jnp.einsum("bhqk,bqhd->bkhd", ds.astype(qp.dtype),
+                                     q_c, preferred_element_type=jnp.float32)
+            return dq, (dk_c, dv_c)
+
+        dq, (dk, dv) = lax.scan(
+            kv_step, jnp.zeros((B, cq, H, D), jnp.float32),
+            (chunks(kp, ck), chunks(vp, ck), dk, dv, chunks(skv, ck), kv_idx),
+        )
+        return (dk, dv), dq
+
+    zeros = jnp.zeros((n_kv, B, ck, H, D), jnp.float32)
+    (dk, dv), dq = lax.scan(
+        q_step, (zeros, zeros),
+        (chunks(qp, cq), chunks(g, cq), lse, delta, chunks(sq, cq), q_idx),
+    )
+
+    def whole(x, like):  # (n, B, c, H, D) -> like's (B, S, H, D)
+        return jnp.moveaxis(x, 0, 1).reshape(like.shape).astype(like.dtype)
+
+    return whole(dq, qp), whole(dk, kp), whole(dv, vp), None, None
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
@@ -241,7 +361,10 @@ def flash_attention(
     chunk_kv: int = 512,
     q_offset: int = 0,
 ) -> jax.Array:
-    """Chunked online-softmax attention with segment masking.  O(c^2) memory."""
+    """Chunked online-softmax attention with segment masking.  O(c^2) memory
+    in the forward and in the backward, which recomputes each block's
+    probabilities from the saved log-sum-exp (FlashAttention-2) instead of
+    keeping every block's scores."""
     B, Sq, H, D = q.shape
     KVH = k.shape[2]
     scale = 1.0 / math.sqrt(D)
@@ -266,25 +389,8 @@ def flash_attention(
     sq = pad_to(segment_ids_q, chunk_q, 1)
     skv = pad_to(segment_ids_kv, chunk_kv, 1)
 
-    Sq_p = qp.shape[1]
-    n_q = Sq_p // chunk_q
-    qp = qp.reshape(B, n_q, chunk_q, H, D)
-    sq_c = sq.reshape(B, n_q, chunk_q)
-    q_idx = (
-        jnp.arange(Sq_p, dtype=jnp.int32).reshape(n_q, chunk_q) + q_offset
-    )
-
-    def one_chunk(xs):
-        q_c, seg_c, idx_c = xs
-        return _flash_q_chunk(
-            q_c, kp, vp, idx_c, seg_c, skv,
-            causal=causal, window=window, chunk_kv=chunk_kv, scale=scale,
-        )
-
-    out = lax.map(
-        one_chunk, (jnp.moveaxis(qp, 1, 0), jnp.moveaxis(sq_c, 1, 0), q_idx)
-    )  # (n_q, B, cq, H, D)
-    out = jnp.moveaxis(out, 0, 1).reshape(B, Sq_p, H, D)
+    out = _flash(qp, kp, vp, sq, skv,
+                 _FlashOpts(causal, window, chunk_q, chunk_kv, q_offset, scale))
     return out[:, :Sq].astype(q.dtype)
 
 

@@ -60,7 +60,12 @@ def chunked_cross_entropy(
     *,
     chunk: int = 512,
     z_loss: float = 1e-4,
+    vocab: Optional[int] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Mean next-token cross-entropy over the positions whose label is not
+    -1, plus ``z_loss`` times the mean squared log-partition.  ``vocab``:
+    the model's vocabulary, when the table has rows past it (``pad_vocab``);
+    those rows are no token, and their logits are left out of the softmax."""
     B, S, d = hidden.shape
     chunk = min(chunk, S)
     pad = (-S) % chunk
@@ -82,6 +87,9 @@ def chunked_cross_entropy(
             "bsd,vd->bsv", h_c.astype(jnp.float32), table.astype(jnp.float32)
         )
         logits = constrain(logits, ("batch_data", None, "vocab"))
+        if vocab is not None and vocab < table.shape[0]:
+            real = jnp.arange(table.shape[0]) < vocab
+            logits = jnp.where(real, logits, -jnp.inf)
         lse = jax.nn.logsumexp(logits, axis=-1)
         idx = jnp.maximum(l_c, 0)
         picked = jnp.take_along_axis(logits, idx[..., None], axis=-1)[..., 0]
@@ -213,9 +221,11 @@ class DecoderLM:
         aux: Dict[str, jax.Array],
     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         x = constrain(x, ("batch", "seq", None))
-        h = norm(p["ln1"], cfg.norm_type, x)
+        with jax.named_scope("norm"):
+            h = norm(p["ln1"], cfg.norm_type, x)
         if char == "A":
-            out, _ = attention(p["mixer"], cfg, h, seg, pos_ids)
+            with jax.named_scope("attention"):
+                out, _ = attention(p["mixer"], cfg, h, seg, pos_ids)
         elif char == "M":
             out, _ = ssm_lib.mamba_forward(p["mixer"], cfg, h)
         elif char == "l":
@@ -224,12 +234,14 @@ class DecoderLM:
             out, _ = xlstm_lib.slstm_forward(p["mixer"], cfg, h)
         x = x + constrain(out, ("batch", "seq", None))
         if "ffn" in p:
-            h = norm(p["ln2"], cfg.norm_type, x)
-            if "router" in p["ffn"]:
-                out, moe_aux = moe_lib.moe_layer(p["ffn"], cfg, h)
-                aux = _add_aux(aux, moe_aux)
-            else:
-                out = mlp(p["ffn"], cfg, h)
+            with jax.named_scope("norm"):
+                h = norm(p["ln2"], cfg.norm_type, x)
+            with jax.named_scope("mlp"):
+                if "router" in p["ffn"]:
+                    out, moe_aux = moe_lib.moe_layer(p["ffn"], cfg, h)
+                    aux = _add_aux(aux, moe_aux)
+                else:
+                    out = mlp(p["ffn"], cfg, h)
             x = x + constrain(out, ("batch", "seq", None))
         return x, aux
 
@@ -242,7 +254,8 @@ class DecoderLM:
         remat_policy: Optional[str] = "nothing",
     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         cfg = self.cfg
-        x = self._embed(params, batch)
+        with jax.named_scope("embed"):
+            x = self._embed(params, batch)
         seg = batch["segment_ids"]
         pos_ids = batch["positions"]
 
@@ -257,8 +270,11 @@ class DecoderLM:
         if remat_policy is not None:
             period_body = _remat(period_body, remat_policy)
 
-        (x, aux), _ = lax.scan(period_body, (x, _zero_aux()), params["blocks"])
-        x = norm(params["final_norm"], cfg.norm_type, x)
+        with jax.named_scope("layers"):
+            (x, aux), _ = lax.scan(period_body, (x, _zero_aux()),
+                                   params["blocks"])
+        with jax.named_scope("norm"):
+            x = norm(params["final_norm"], cfg.norm_type, x)
         return x, aux
 
     def loss(
@@ -269,9 +285,11 @@ class DecoderLM:
         remat_policy: Optional[str] = "nothing",
     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         x, aux = self.hidden_states(params, batch, remat_policy=remat_policy)
-        loss, metrics = chunked_cross_entropy(
-            x, self._table(params), batch["labels"]
-        )
+        with jax.named_scope("loss"):
+            loss, metrics = chunked_cross_entropy(
+                x, self._table(params), batch["labels"],
+                vocab=self.cfg.vocab_size,
+            )
         loss = loss + aux["moe_load_balance"] + aux["moe_z_loss"]
         metrics = dict(metrics, **aux, loss=loss)
         return loss, metrics

@@ -7,7 +7,8 @@ device planes; with no trace running it costs about a microsecond.  A
 process that has not loaded JAX cannot be tracing, so there it is one
 shared ``nullcontext`` and JAX is never imported for it: the sleep
 payload runs without JAX.  ``open_span``/``close_span`` bound an interval
-that begins and ends in different callbacks of the event-loop thread.
+that begins and ends in different callbacks of the event-loop thread;
+``set_stats`` gives an open span ids known only at its end.
 
 Every name below is read by the chip benchmark's reduction
 (``benchmarks/chip/program_trace.py``); the value says what reads it.
@@ -18,7 +19,7 @@ from __future__ import annotations
 import sys
 from contextlib import nullcontext
 
-__all__ = ["SPANS", "span", "open_span", "close_span"]
+__all__ = ["SPANS", "span", "open_span", "close_span", "set_stats"]
 
 SPANS = {
     "repro.live.run": "batch_cold_start_ms; program_gaps",
@@ -31,6 +32,8 @@ SPANS = {
     "repro.payload.kernel":
         "payload_kernel_ms; executor_hop_ms; batch_cold_start_ms",
     "repro.payload.pad": "program_gaps",
+    "repro.data.pack": "pack_fill (its ``real_tokens`` and ``slots``)",
+    "repro.train.batch": "batch_wait_ms",
 }
 
 _NULL = nullcontext()
@@ -53,3 +56,9 @@ def open_span(name: str, **ids):
 
 def close_span(s) -> None:
     s.__exit__(None, None, None)
+
+
+def set_stats(s, **ids) -> None:
+    """Add ``ids`` to the open span ``s`` (nothing when not tracing)."""
+    if s is not None:
+        s.set_metadata(**ids)

@@ -146,9 +146,10 @@ def make_train_step(
         if compressor is not None:
             grads, ef_state = compressor.apply(grads, ef_state)
 
-        params_new, opt_new, opt_metrics = adamw_update(
-            params, grads, opt_core, opt_cfg
-        )
+        with jax.named_scope("optimizer"):
+            params_new, opt_new, opt_metrics = adamw_update(
+                params, grads, opt_core, opt_cfg
+            )
         if ef_state is not None:
             opt_new["ef"] = ef_state
         metrics = dict(metrics, **opt_metrics)

@@ -7,6 +7,7 @@ metadata."""
 
 import glob
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,10 @@ from repro.models import DecoderLM, init_params
 from repro.obs import spans
 from repro.runtime import Master, RuntimeConfig, ScaledClock, SleepPayload, run_live
 from repro.scenarios.registry import get_scenario
+
+
+# the spans of the training input path; a live run opens the others
+TRAINING_SPANS = {"repro.data.pack", "repro.train.batch"}
 
 
 def _host_events(path):
@@ -55,7 +60,7 @@ def test_traced_live_run_emits_every_span(traced_live_run):
     res, events = traced_live_run
     assert res.completed == res.total == 8
     names = {e[0] for e in events}
-    assert names == set(spans.SPANS)
+    assert names == set(spans.SPANS) - TRAINING_SPANS
     count = {n: sum(e[0] == n for e in events) for n in names}
     assert count["repro.live.run"] == count["repro.live.setup"] == 1
     assert count["repro.payload.call"] == count["repro.payload.kernel"] == 8
@@ -134,9 +139,48 @@ def _tiny_lm():
     return model, params, batch
 
 
-@pytest.mark.parametrize("program", ["prefill", "decode_step"])
+@pytest.mark.timeout(120)
+def test_training_input_emits_its_spans(tmp_path):
+    """Each batch the packer emits is a ``repro.data.pack`` span with its
+    real tokens and slots; each wait of the step loop for the next device
+    batch a ``repro.train.batch`` span."""
+    from repro.distributed.sharding import make_rules
+    from repro.launch.mesh import make_local_mesh
+    from repro.launch.train import device_batches, host_batches
+
+    model, _, _ = _tiny_lm()
+    mesh = make_local_mesh()
+    with jax.profiler.trace(str(tmp_path)):
+        got = [b["tokens"].shape for _, b in zip(range(3), device_batches(
+            host_batches(model.cfg, 32, 4, seed=1), mesh, make_rules(mesh)))]
+    assert got == [(4, 32)] * 3
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    events = _host_events(path)
+    assert {e[0] for e in events} == TRAINING_SPANS
+    waits = [e for e in events if e[0] == "repro.train.batch"]
+    packs = [e[3] for e in events if e[0] == "repro.data.pack"]
+    assert len(waits) >= 3 and len(packs) >= 3
+    assert all(p["slots"] == 4 * 32 and 0 < p["real_tokens"] <= 4 * 32
+               for p in packs)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode_step", "train_step"])
 def test_model_step_ops_carry_their_scopes(program):
     model, params, batch = _tiny_lm()
+    if program == "train_step":
+        from repro.training import (OptimizerConfig, init_opt_state,
+                                    make_train_step)
+
+        batch = dict(batch, labels=batch["tokens"])
+        fn = jax.jit(make_train_step(model, OptimizerConfig()))
+        hlo = fn.lower(params, init_opt_state(params), batch).compile(
+            ).as_text()
+        # autodiff wraps a scope's name: ``transpose(jvp(embed))/...``
+        for scope in ("embed", "layers", "attention", "mlp", "norm", "loss",
+                      "optimizer"):
+            assert re.search(rf"[/(]{scope}[)/]", hlo), scope
+        return
     if program == "prefill":
         fn = jax.jit(lambda p, b: model.prefill(p, b, max_len=16))
         args = (params, batch)
