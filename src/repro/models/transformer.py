@@ -5,7 +5,9 @@ heterogeneous) ``layer_pattern`` — e.g. jamba's ``MMMMAMMM`` — with the
 pattern unrolled inside the scan body and per-position parameters stacked
 over periods.  This keeps the HLO size O(period) regardless of depth (95
 layers compile as 1 scanned period body), which is what makes the 512-device
-dry-run of the large configs tractable.
+dry-run of the large configs tractable.  ``decode_step`` alone unrolls its
+scan, trading compile time that grows with depth for reading each weight
+once a step (see there).
 
 Three entry points, matching the assigned input shapes:
   - ``loss``        : training forward + chunked cross-entropy (train_4k)
@@ -419,10 +421,15 @@ class DecoderLM:
                     x = x + out
             return (x, kv), new_states
 
+        # unrolled: a step uses each weight once, and over a rolled loop the
+        # compiler rounds every whole weight stack to the matmuls' operand
+        # type before the loop, writing a copy it then reads back; with
+        # static slices each weight's rounding fuses into its matmul
         with jax.named_scope("layers"):
             (x, kv), states = lax.scan(
                 period_body, (x, kv),
                 (params["blocks"], jnp.arange(cfg.n_periods), states),
+                unroll=True,
             )
         with jax.named_scope("norm"):
             x = norm(params["final_norm"], cfg.norm_type, x)

@@ -7,6 +7,8 @@ asserting output shapes and the absence of NaNs.  The FULL configs are only
 ever exercised via the dry-run (ShapeDtypeStructs, no allocation).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -397,3 +399,29 @@ def test_decode_step_matches_per_layer_reference(arch, window):
                     assert np.all(np.any(new[:, b, written] != 0,
                                          axis=(-2, -1)))
                     assert not np.any(new[:, b, new_len[b]:])
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "qwen3-8b", "qwen3-moe-30b-a3b",
+                                  "jamba-v0.1-52b"])
+def test_only_decode_step_unrolls_its_layer_loop(arch, built):
+    """``prefill`` and the train step lower their layer scan to a ``while``
+    (so their HLO stays one period long), while ``decode_step`` lowers to
+    straight-line layers: dense, GQA, MoE and hybrid alike."""
+    cfg, model, params, batch = built(arch)
+    prompt = {k: v for k, v in batch.items() if k != "labels"}
+    programs = {
+        "prefill": jax.jit(lambda p, b: model.prefill(p, b, max_len=S + 2))
+        .lower(params, prompt),
+        "train_step": jax.jit(make_train_step(model, OptimizerConfig()))
+        .lower(params, init_opt_state(params), batch),
+        "decode_step": jax.jit(model.decode_step).lower(
+            params, {"tokens": batch["tokens"][:, :1]},
+            model.init_cache(B, S + 2, dtype=jnp.float32)),
+    }
+    # a while's location is its scope: ``.../layers/while``, and under
+    # autodiff ``.../jvp(layers)/while``, ``transpose(jvp(layers))/while``
+    layer_loop = re.compile(r'loc\("[^"]*\blayers\)*/while"')
+    for name, lowered in programs.items():
+        text = lowered.as_text(debug_info=True)
+        assert (layer_loop.search(text) is None) == (name == "decode_step"), \
+            name
